@@ -19,7 +19,11 @@ processes against a localhost coordinator (the ``REPRO_COORD_ADDR`` env
 contract).  Each worker sweeps only its ``process_slice`` of the shared
 spec list -- its aggregate's ``n_scenarios`` proves it built batches for
 its slice alone -- and the parent merges the raw per-process aggregates
-out-of-band and checks parity against a single-process sweep.
+out-of-band and checks parity against a single-process sweep.  It is a
+CPU test of the host-side partitioning, never a chip run: the workers
+are pinned to the CPU backend (``JAX_PLATFORMS=cpu``), and the parent
+has already imported the engine stack, which would hold a chip for
+itself.
 
     PYTHONPATH=src python -m benchmarks.engine_fleet [--fast]
     PYTHONPATH=src python -m benchmarks.engine_fleet --distributed-smoke
@@ -256,6 +260,7 @@ def run_distributed_smoke(timeout_s: float = 420.0) -> None:
             REPRO_NUM_PROCESSES="2",
             REPRO_PROCESS_ID=str(pid),
             XLA_FLAGS="--xla_force_host_platform_device_count=4",
+            JAX_PLATFORMS="cpu",
         )
         procs.append(subprocess.Popen(
             [sys.executable, "-c", _WORKER_BOOT, path],

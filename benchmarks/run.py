@@ -23,6 +23,8 @@ def main(argv=None) -> int:
                     help="comma-separated subset, e.g. e1,e7")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bidding_bench, cluster_24h, e1_calibration,
                             e2_step_response, e3_ar4, e4_closed_loop,
                             e7_fr_latency, e8_multicountry, e9_reserve,

@@ -57,7 +57,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 import repro.core.dispatch as dispatch
@@ -665,9 +664,9 @@ def _sharded_seconds_fn(cfg: EngineConfig, reduce: str, mesh,
                                            base_loads, load_keys, scan_keys,
                                            ops)
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             run, mesh=mesh, in_specs=(spec,) * 6,
-            out_specs=spec, check_rep=False))
+            out_specs=spec, check_vma=False))
         _SHARDED_CACHE[key] = fn
     return fn
 
@@ -677,10 +676,10 @@ def _sharded_hourly_fn(cfg: EngineConfig, mesh, has_ops: bool = False):
     fn = _SHARDED_CACHE.get(key)
     if fn is None:
         spec = P(_SCENARIO_AXIS)
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             partial(_engine_hourly_vmapped, cfg), mesh=mesh,
             in_specs=(spec, spec), out_specs=spec,
-            check_rep=False))
+            check_vma=False))
         _SHARDED_CACHE[key] = fn
     return fn
 
@@ -1084,9 +1083,9 @@ def _sweep_step_sharded(cfg: EngineConfig, mesh):
             merged = summary_merge(local, _sweep_body(cfg, batch, lane))
             return jax.tree.map(lambda x: x[None], merged)
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             run, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_rep=False), donate_argnums=(0,))
+            check_vma=False), donate_argnums=(0,))
         _SHARDED_CACHE[key] = fn
     return fn
 

@@ -69,7 +69,7 @@ def _farr(x) -> jax.Array:
     """float32 unless the input is already a wider float.
 
     Every f32 (and weakly-typed) input produces the exact pre-existing
-    float32 graph; float64 inputs under ``jax.experimental.enable_x64``
+    float32 graph; float64 inputs under ``jax.enable_x64(True)``
     keep full precision so the finite-difference gradcheck harness can
     compare against ``jax.grad`` below f32 roundoff.
     """

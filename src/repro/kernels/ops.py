@@ -1,43 +1,31 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute with interpret=True -- the
-kernel body runs in Python per grid step, validating the exact TPU
-program.  On a real TPU backend `interpret` defaults to False and the
-kernels compile to Mosaic.
+The kernels compile to Mosaic for the TPU.  ``interpret=True`` runs the
+kernel body in Python per grid step instead (how the CPU tests validate
+the exact TPU program); it happens only when the caller asks for it, so
+a kernel can never drop into the interpreter unnoticed on a chip.
 """
 from __future__ import annotations
-
-import jax
 
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.pid_update import pid_update as _pid
 from repro.kernels.ssd_scan import ssd_scan as _ssd
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool | None = None):
-    if interpret is None:
-        interpret = _default_interpret()
+                    interpret: bool = False):
     return _flash(q, k, v, causal=causal, window=window,
                   block_q=block_q, block_k=block_k, interpret=interpret)
 
 
 def ssd_scan(x, dt, A, B, C, *, chunk: int = 256, block_heads: int = 4,
-             interpret: bool | None = None):
-    if interpret is None:
-        interpret = _default_interpret()
+             interpret: bool = False):
     return _ssd(x, dt, A, B, C, chunk=chunk, block_heads=block_heads,
                 interpret=interpret)
 
 
 def pid_update(target, power, temp, integ, prev_err, *, dt_s: float = 0.005,
-               interpret: bool | None = None):
-    if interpret is None:
-        interpret = _default_interpret()
+               interpret: bool = False):
     return _pid(target, power, temp, integ, prev_err, dt_s=dt_s,
                 interpret=interpret)

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _segsum_tril(dA):
     """dA: (bh, q). Returns (bh, q, q) with out[h,i,j] = sum_{j<k<=i} dA[h,k]
@@ -118,7 +116,7 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 256, block_heads: int = 4,
                                lambda b_, h_, c_: (b_, c_, h_, 0)),
         out_shape=jax.ShapeDtypeStruct((b, s, nh, hd), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_heads, hd, ds), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -27,6 +27,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.configs import get_arch
     from repro.configs.base import ShapeConfig
     from repro.launch.mesh import make_local_mesh

@@ -64,24 +64,10 @@ def shapes_tree(specs):
 # ---------------------------------------------------------------------------
 
 
-def _active_mesh():
-    """The mesh of the enclosing ``with Mesh(...)`` context, or None.
-
-    ``jax.sharding.get_abstract_mesh`` only exists on newer JAX; on 0.4.x
-    the lookalike private helper returns a raw context tuple, so there the
-    active mesh is read from ``thread_resources.env.physical_mesh`` instead.
-    """
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        return get_abstract()
-    mesh = jax.interpreters.pxla.thread_resources.env.physical_mesh
-    return None if mesh.empty else mesh
-
-
 def shard(x: jax.Array, *spec) -> jax.Array:
     """with_sharding_constraint if inside a mesh context, else identity."""
-    mesh = _active_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     names = set(mesh.axis_names)
 

@@ -346,9 +346,11 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.service.loadgen import LoadGen, LoadGenConfig
 
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     cfg = ServiceConfig(capacity=args.capacity or args.sites,
                         horizon_h=args.horizon_h, tick_hz=args.tick_hz,
                         port=args.port, seed=args.seed)

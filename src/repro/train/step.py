@@ -232,7 +232,6 @@ def make_compressed_train_step(model: Model, rules: MeshRules,
     quantise the wire payload: 4x fewer collective bytes on the DP
     all-reduce.  The EF residual lives per-device (leading device axis).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.optim.compress import ef_compress
@@ -278,12 +277,12 @@ def make_compressed_train_step(model: Model, rules: MeshRules,
 
     def train_step(params, opt_state, residual, batch, step):
         b_spec = jax.tree.map(lambda _: P(batch_entry), batch)
-        loss, metrics, grads, new_res = shard_map(
+        loss, metrics, grads, new_res = jax.shard_map(
             sync_block, mesh=mesh,
             in_specs=(p_spec, b_spec, res_spec),
             out_specs=(P(), jax.tree.map(lambda _: P(), metrics_spec(model)),
                        p_spec, res_spec),
-            check_rep=False,
+            check_vma=False,
         )(params, batch, residual)
         lr = warmup_cosine(step, **lr_kw)
         params, opt_state, opt_metrics = adamw_update(

@@ -3,7 +3,7 @@ term and the full ensemble settlement objective.
 
 A silent gradient bug in the bidding optimiser would corrupt every
 downstream commitment, so the check is strict: float64 (via
-``jax.experimental.enable_x64`` -- the objective stack follows input
+``jax.enable_x64`` -- the objective stack follows input
 dtype) with a max relative error of 1e-3 for every term, parameterised
 over ALL ``PRODUCT_ORDER`` products and both ``pue_aware`` settings.
 Check points sit in the interior of each term's smooth pieces (the hard
@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 import repro.core.tier3 as tier3
 import repro.grid.markets as markets
@@ -29,7 +28,7 @@ AWARE = [True, False]
 def fd_vs_ad(f, x0: float, h: float = 1e-6) -> float:
     """Max-relative-error between jax.grad and a central difference,
     both evaluated in float64."""
-    with enable_x64():
+    with jax.enable_x64(True):
         x = jnp.float64(x0)
         ad = float(jax.grad(f)(x))
         fd = float((f(x + h) - f(x - h)) / (2.0 * h))
@@ -111,7 +110,7 @@ def _ens_obj(mu, rho, bid, ens, product_idx, *, pue_aware, smooth):
 def test_ensemble_settlement_objective_grad(product_idx, pue_aware, smooth):
     """The full ensemble settlement objective -- what the optimiser
     actually differentiates (smooth) and ranks with (hard)."""
-    with enable_x64():
+    with jax.enable_x64(True):
         ens = _ensemble64()
     point = {"mu": 0.75, "rho": 0.2, "bid": 0.18}
     for wrt, x0 in point.items():
@@ -132,7 +131,7 @@ def test_no_nan_or_zero_grad_at_residual_load_boundary(side):
     could stall in), which is exactly what the sigmoid gate removes."""
     rho_b = 0.30
     mu_b = tier3.MIN_RESIDUAL_LOAD + rho_b + side
-    with enable_x64():
+    with jax.enable_x64(True):
         ens = _ensemble64()
 
         def f_mu(mu):
