@@ -153,7 +153,7 @@ def write_report(fname: str = "bench_report.json", **extra) -> str:
         rows=_ROWS,
         entries=_ENTRIES,
         spans=[r for r in tr.records if r["kind"] == "span"],
-        span_summaries=tr.metrics.all_summaries(),
+        observations=tr.metrics.all_summaries(),
         counters=tr.metrics.counters,
         **extra)
     return save_json(fname, payload)

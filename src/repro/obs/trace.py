@@ -3,7 +3,7 @@
 The measurement substrate of the control plane.  The in-graph half of the
 observability subsystem (``repro.obs.telemetry``) meters what happens
 *inside* the fused rollout; this module meters everything around it --
-wall-clock spans of dispatch/train/serve/benchmark phases, point events
+wall-clock spans of dispatch/train/serve/service phases, point events
 (the trainer's ``ffr_shed`` / ``grid_ckpt`` markers, the serving loop's
 batch-thinning), and scalar counters/observations -- and exports all of
 it as machine-readable JSONL so ``python -m repro.obs.report`` (or any
@@ -13,18 +13,29 @@ Design constraints, in order:
 
   * zero setup: a module-level default :class:`Tracer` (``obs.trace.span``
     / ``obs.trace.event`` / ``obs.metrics``) so call sites are one-liners,
-  * cheap enough for per-step use: recording a span is two
-    ``perf_counter`` calls and one dict append (no I/O until
-    :meth:`Tracer.export_jsonl`),
+  * on the profiler's clock: every span also holds a
+    ``jax.profiler.TraceAnnotation`` of the same name open for its whole
+    duration, so inside a ``jax.profiler`` trace (:func:`profile`) the
+    program's spans sit on the host plane beside the device's ``XLA
+    Modules`` line and device idle time can be laid against them,
+  * cheap enough for per-trigger use: a span is a small slotted context
+    manager -- two ``perf_counter`` calls and one append of the span to a
+    ring; the record dict is built only when the records are read, and
+    the annotation is built only while a profiler records (about 1.5 us
+    a span with no profiler running),
+  * bounded: ``Tracer.records`` keeps the newest :data:`RECORDS_MAX`
+    span/event records in a ring, so an always-on service holds a fixed
+    amount of trace whatever its uptime (counters and observation series
+    in :class:`Metrics` are the caller's to bound),
   * schema-stable records: every line is one JSON object with a ``kind``
     (``span`` | ``event`` | ``counter`` | ``observation``), a ``name``, a
     unix ``ts``, and a flat ``attrs`` dict; spans add ``wall_s`` (full
     float precision -- sub-10 ms spans are exactly the scale of the
     paper's 97.2 ms claim) and ``parent`` (the enclosing span's name).
 
-An opt-in :func:`profile` hook wraps a block in ``jax.profiler.trace``
-when a directory is given (or ``REPRO_JAX_PROFILE_DIR`` is set), so the
-same call sites can produce device-level traces without code changes.
+:func:`profile` wraps a block in ``jax.profiler.trace`` when a directory
+is given (or ``REPRO_JAX_PROFILE_DIR`` is set), so the same call sites
+produce device-level traces without code changes.
 """
 from __future__ import annotations
 
@@ -32,10 +43,15 @@ import json
 import os
 import threading
 import time
+from collections import deque
 from contextlib import contextmanager
-from typing import Optional
+from typing import Iterable, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+# span/event records a Tracer keeps (the newest; older ones drop out)
+RECORDS_MAX = 65_536
 
 
 class Metrics:
@@ -59,6 +75,14 @@ class Metrics:
     def observe(self, name: str, value: float) -> None:
         with self._lock:
             self._series.setdefault(name, []).append(float(value))
+
+    def observe_many(self, name: str, values: Iterable[float]) -> None:
+        """Append every value to one series under one lock (nothing, and
+        no empty series, when there are none)."""
+        xs = np.asarray(values, np.float64).ravel().tolist()
+        if xs:
+            with self._lock:
+                self._series.setdefault(name, []).extend(xs)
 
     @property
     def counters(self) -> dict[str, float]:
@@ -91,59 +115,104 @@ class Metrics:
             self._series.clear()
 
 
-class Tracer:
-    """Span/event recorder with a thread-local span stack.
+class Span:
+    """One span: ``with tracer.span(name, **attrs) as attrs``.
 
-    Spans nest: the record's ``parent`` is the name of the enclosing span
-    on the same thread (or None at top level).  The context manager
-    yields the record's mutable ``attrs`` dict so call sites can attach
-    results discovered mid-span (e.g. the post-shed batch size).
+    Entering pushes the name on the thread's span stack, opens the
+    profiler annotation and starts the clock; leaving stops it, closes
+    the annotation and puts the span itself in the tracer's ring, where
+    :attr:`Tracer.records` turns it into a record dict when read.
+    """
+
+    __slots__ = ("name", "attrs", "parent", "t0", "wall_s",
+                 "_ring", "_stack", "_note")
+
+    def __init__(self, tracer: "Tracer", name: str, attrs: dict):
+        self._ring = tracer._ring
+        self.name = name
+        self.attrs = attrs
+
+    def __enter__(self) -> dict:
+        stack = self._stack = _thread_stack()
+        self.parent = stack[-1] if stack else None
+        stack.append(self.name)
+        # the annotation only where a profiler is recording: building one
+        # costs a third of a span
+        self._note = None
+        if TraceAnnotation.is_enabled():
+            self._note = TraceAnnotation(self.name)
+            self._note.__enter__()
+        self.t0 = time.perf_counter()
+        return self.attrs
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        if self._note is not None:
+            self._note.__exit__(*exc)
+            self._note = None
+        self.wall_s = t1 - self.t0
+        self._stack.pop()
+        self._ring.append(self)
+
+
+_LOCAL = threading.local()
+
+
+def _thread_stack() -> list:
+    """The names of the spans open on this thread, innermost last."""
+    try:
+        return _LOCAL.stack
+    except AttributeError:
+        _LOCAL.stack = []
+        return _LOCAL.stack
+
+
+class Tracer:
+    """Span/event recorder; spans nest per thread.
+
+    The record's ``parent`` is the name of the enclosing span on the same
+    thread, of whichever tracer (or None at top level).  The context
+    manager yields the record's mutable ``attrs`` dict so call sites can
+    attach results discovered mid-span (e.g. the post-shed batch size).
     """
 
     def __init__(self, metrics: Optional[Metrics] = None):
-        self.records: list[dict] = []
+        # deque.append is atomic: recording takes no lock
+        self._ring: deque = deque(maxlen=RECORDS_MAX)
         self.metrics = metrics if metrics is not None else Metrics()
-        self._local = threading.local()
-        self._lock = threading.Lock()
+        # unix time of perf_counter's zero: a span's ``ts`` without a
+        # second clock read per span
+        self._epoch = time.time() - time.perf_counter()
+
+    def _record(self, r) -> dict:
+        if not isinstance(r, Span):
+            return r
+        return dict(kind="span", name=r.name, ts=self._epoch + r.t0,
+                    parent=r.parent, attrs=r.attrs, wall_s=r.wall_s)
+
+    @property
+    def records(self) -> list[dict]:
+        """The kept span/event records, oldest first."""
+        return [self._record(r) for r in list(self._ring)]
 
     # -- recording ---------------------------------------------------------
-    def _stack(self) -> list:
-        st = getattr(self._local, "stack", None)
-        if st is None:
-            st = self._local.stack = []
-        return st
-
-    @contextmanager
-    def span(self, name: str, **attrs):
+    def span(self, name: str, **attrs) -> Span:
         """Time a block; record {kind, name, ts, wall_s, parent, attrs}."""
-        stack = self._stack()
-        rec = dict(kind="span", name=name, ts=time.time(),
-                   parent=stack[-1] if stack else None, attrs=dict(attrs))
-        stack.append(name)
-        t0 = time.perf_counter()
-        try:
-            yield rec["attrs"]
-        finally:
-            rec["wall_s"] = time.perf_counter() - t0
-            stack.pop()
-            with self._lock:
-                self.records.append(rec)
-            self.metrics.observe(f"span.{name}", rec["wall_s"])
+        return Span(self, name, attrs)
 
     def event(self, name: str, **attrs) -> dict:
         """Record a point event; returns the (mutable) attrs dict."""
-        rec = dict(kind="event", name=name, ts=time.time(), attrs=attrs)
-        with self._lock:
-            self.records.append(rec)
+        self._ring.append(dict(kind="event", name=name, ts=time.time(),
+                               attrs=attrs))
         return attrs
 
     # -- querying ----------------------------------------------------------
     def spans(self, name: Optional[str] = None) -> list[dict]:
-        return [r for r in self.records if r["kind"] == "span"
-                and (name is None or r["name"] == name)]
+        return [self._record(r) for r in list(self._ring)
+                if isinstance(r, Span) and (name is None or r.name == name)]
 
     def events(self, name: Optional[str] = None) -> list[dict]:
-        return [r for r in self.records if r["kind"] == "event"
+        return [r for r in list(self._ring) if isinstance(r, dict)
                 and (name is None or r["name"] == name)]
 
     # -- export ------------------------------------------------------------
@@ -162,8 +231,7 @@ class Tracer:
         return path
 
     def clear(self) -> None:
-        with self._lock:
-            self.records.clear()
+        self._ring.clear()
         self.metrics.clear()
 
 

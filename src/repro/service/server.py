@@ -12,14 +12,18 @@ An asyncio dispatch loop around a :class:`~repro.service.state.SiteStore`:
     tests and the load generator drive,
   * **sub-second FFR triggers** take the deterministic island bypass: one
     precomputed per-site cap-row write into the numpy register file,
-    recorded as a per-site ``serve.ffr_response`` span -- no JAX, no
+    recorded as a per-site ``service.ingest`` span -- no JAX, no
     allocation on the decide path.  The physics catches up at the next
     batched tick (the Tier-2 correction), and the full
     trigger-to-physics-applied latency is observed as
     ``service.trigger_to_target_ms`` -- the number the benchmark gates
     against the 700 ms FFR budget,
-  * **the tick** advances every resident site with the SiteStore's single
-    donated-buffer batched ``engine_step``,
+  * **the tick** (``service.tick``) advances every resident site with the
+    SiteStore's single donated-buffer batched ``engine_step``: its enqueue
+    is ``service.dispatch``, the blocking fetch of the shed/trigger flags
+    ``service.readback``; every span is also a ``jax.profiler``
+    annotation, so ``REPRO_JAX_PROFILE_DIR`` (the CLI's profile hook)
+    lays them beside the device's programs,
   * **graceful degradation** -- a site whose feed goes stale past
     ``late_after_s`` is quarantined *individually* (its lane freezes, the
     rest of the fleet keeps ticking -- no global stall) and rejoins
@@ -149,19 +153,16 @@ class ServiceServer:
         One precomputed cap-row write into the register file -- the
         actuator interface, exactly the SafetyIsland's hot path -- then
         the trigger is queued for the next batched tick (the physics-side
-        Tier-2 correction).  Returns the bypass write time in ms; the
-        whole response is a per-site ``serve.ffr_response`` span.
+        Tier-2 correction).  Both are a per-site ``service.ingest`` span;
+        returns its time in ms.
         """
-        with trace.span("serve.ffr_response", site=int(slot)) as at:
-            t0 = time.perf_counter_ns()
+        sp = trace.span("service.ingest", site=int(slot))
+        with sp:
             self.caps[slot] = self.shed_caps[slot]
             if self.pending_trig_ns[slot] == 0:
-                self.pending_trig_ns[slot] = t0
-            dt_ms = (time.perf_counter_ns() - t0) * 1e-6
-            at["island_ms"] = dt_ms
+                self.pending_trig_ns[slot] = time.perf_counter_ns()
         trace.metrics.inc("service.triggers")
-        trace.metrics.observe("service.island_write_ms", dt_ms)
-        return dt_ms
+        return sp.wall_s * 1e3
 
     def ingest_tick(self, slot: int, freq_hz: Optional[float] = None,
                     price: Optional[float] = None,
@@ -207,58 +208,63 @@ class ServiceServer:
 
     # -- the tick ------------------------------------------------------------
     def step_once(self) -> dict:
-        """One service tick: quarantine sweep, batched engine step,
-        trigger-to-target resolution, cap-row restore."""
-        now = time.perf_counter_ns()
-        # late-tick detection -> per-site quarantine, never a global stall
-        seen = self.last_tick_ns > 0
-        late = (self.slot_active & seen
-                & (now - self.last_tick_ns
-                   > int(self.cfg.late_after_s * 1e9)))
-        newly = late & ~self.quarantined
-        recovered = self.quarantined & ~late
-        if newly.any():
-            trace.metrics.inc("service.quarantined", int(newly.sum()))
-            for s in np.nonzero(newly)[0]:
-                trace.event("service.quarantine", site=int(s))
-        if recovered.any():
-            trace.metrics.inc("service.recovered", int(recovered.sum()))
-        self.quarantined = late
+        """One service tick (a ``service.tick`` span): quarantine sweep,
+        batched engine step (``service.dispatch``), read-back of its flags
+        (``service.readback``), trigger-to-target resolution, cap-row
+        restore."""
+        with trace.span("service.tick"):
+            now = time.perf_counter_ns()
+            # late-tick detection -> per-site quarantine, never a global
+            # stall
+            seen = self.last_tick_ns > 0
+            late = (self.slot_active & seen
+                    & (now - self.last_tick_ns
+                       > int(self.cfg.late_after_s * 1e9)))
+            newly = late & ~self.quarantined
+            recovered = self.quarantined & ~late
+            if newly.any():
+                trace.metrics.inc("service.quarantined", int(newly.sum()))
+                for s in np.nonzero(newly)[0]:
+                    trace.event("service.quarantine", site=int(s))
+            if recovered.any():
+                trace.metrics.inc("service.recovered", int(recovered.sum()))
+            self.quarantined = late
 
-        below = ((self.freq_hz < self.trig_hz)
-                 | (self.pending_trig_ns > 0)) & self.slot_active
-        enabled = ~self.quarantined
-        t0 = time.perf_counter()
-        out = self.store.step(below, enabled)
-        shed = np.asarray(out.shed)
-        trig = np.asarray(out.trig)
-        t_done_ns = time.perf_counter_ns()
-        step_ms = (time.perf_counter() - t0) * 1e3
+            below = ((self.freq_hz < self.trig_hz)
+                     | (self.pending_trig_ns > 0)) & self.slot_active
+            enabled = ~self.quarantined
+            t0 = time.perf_counter()
+            out = self.store.step(below, enabled)
+            # blocks until the device has run the step
+            with trace.span("service.readback"):
+                shed = np.asarray(out.shed)
+                trig = np.asarray(out.trig)
+            t_done_ns = time.perf_counter_ns()
+            step_ms = (time.perf_counter() - t0) * 1e3
 
-        # resolve trigger-to-target: pending triggers consumed by this
-        # tick (quarantined lanes stay pending until they rejoin)
-        consumed = (self.pending_trig_ns > 0) & enabled & self.slot_active
-        for s in np.nonzero(consumed)[0]:
-            trace.metrics.observe(
+            # resolve trigger-to-target: pending triggers consumed by this
+            # tick (quarantined lanes stay pending until they rejoin)
+            consumed = (self.pending_trig_ns > 0) & enabled & self.slot_active
+            trace.metrics.observe_many(
                 "service.trigger_to_target_ms",
-                (t_done_ns - self.pending_trig_ns[s]) * 1e-6)
-        self.pending_trig_ns[consumed] = 0
+                (t_done_ns - self.pending_trig_ns[consumed]) * 1e-6)
+            self.pending_trig_ns[consumed] = 0
 
-        # restore armed cap rows when a shed window closes
-        done = self._prev_shed & ~shed
-        if done.any():
-            self.caps[done] = self.armed_caps[done]
-        self._prev_shed = shed
+            # restore armed cap rows when a shed window closes
+            done = self._prev_shed & ~shed
+            if done.any():
+                self.caps[done] = self.armed_caps[done]
+            self._prev_shed = shed
 
-        self.tick_count += 1
-        trace.metrics.inc("service.ticks")
-        trace.metrics.observe("service.step_ms", step_ms)
-        return dict(tick=self.tick_count, step_ms=step_ms,
-                    n_run=int((self.slot_active & enabled).sum()),
-                    n_quarantined=int(self.quarantined.sum()),
-                    n_shedding=int(shed.sum()),
-                    n_triggered=int(trig.sum()),
-                    n_resolved=int(consumed.sum()))
+            self.tick_count += 1
+            trace.metrics.inc("service.ticks")
+            trace.metrics.observe("service.step_ms", step_ms)
+            return dict(tick=self.tick_count, step_ms=step_ms,
+                        n_run=int((self.slot_active & enabled).sum()),
+                        n_quarantined=int(self.quarantined.sum()),
+                        n_shedding=int(shed.sum()),
+                        n_triggered=int(trig.sum()),
+                        n_resolved=int(consumed.sum()))
 
     # -- the dispatch loop ---------------------------------------------------
     async def serve(self, n_ticks: Optional[int] = None,
@@ -359,7 +365,8 @@ def main(argv=None) -> int:
     gen = LoadGen(LoadGenConfig(n_ticks=args.ticks,
                                 trigger_rate_per_site_day=args.trigger_rate,
                                 seed=args.seed))
-    stats = asyncio.run(gen.drive(server, slots))
+    with trace.profile():  # opt-in device trace: REPRO_JAX_PROFILE_DIR
+        stats = asyncio.run(gen.drive(server, slots))
     print(f"served {stats['ticks']} ticks x {len(slots)} sites: "
           f"{stats['ticks_per_s']:.1f} ticks/s, "
           f"{stats['n_triggers']} triggers, "

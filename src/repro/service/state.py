@@ -44,6 +44,7 @@ import repro.grid.markets as markets
 import repro.workload.model as workload_lib
 from repro.core.engine import EngineConfig, EngineParams, EngineState
 from repro.grid.scenarios import ScenarioBatch
+from repro.obs import trace
 
 
 class StoreState(NamedTuple):
@@ -249,9 +250,12 @@ class SiteStore:
             below = np.zeros((self.capacity,), bool)
         if enabled is None:
             enabled = np.ones((self.capacity,), bool)
-        self.state, out = _service_step(
-            self.cfg, self.sched_s, self.state,
-            jnp.asarray(below, bool), jnp.asarray(enabled, bool))
+        # the host->device copies and the enqueue: JAX returns before the
+        # device finishes, so the device's share shows in the read-back
+        with trace.span("service.dispatch"):
+            self.state, out = _service_step(
+                self.cfg, self.sched_s, self.state,
+                jnp.asarray(below, bool), jnp.asarray(enabled, bool))
         return out
 
     # -- introspection (tests/bench) ----------------------------------------

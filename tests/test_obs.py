@@ -3,6 +3,7 @@ telemetry taps against host-side numpy oracles, and the hard gate that
 ``telemetry=False`` leaves the engine's compiled graph bit-identical."""
 import dataclasses
 import io
+import time
 
 import jax
 import jax.numpy as jnp
@@ -37,8 +38,10 @@ def test_span_records_nesting_and_attrs():
     assert outer["attrs"] == {"a": 1}
     assert inner["attrs"]["found"] == 42
     assert outer["wall_s"] >= inner["wall_s"] >= 0.0
-    # spans auto-observe their wall time
-    assert tr.metrics.summary("span.inner")["count"] == 1
+    # one record per span, in the order they closed, and no second copy of
+    # the wall time as an observation series
+    assert [r["name"] for r in tr.records] == ["inner", "outer"]
+    assert tr.metrics.all_summaries() == []
 
 
 def test_span_records_on_exception():
@@ -51,6 +54,44 @@ def test_span_records_on_exception():
     with tr.span("after"):
         pass
     assert tr.spans("after")[0]["parent"] is None
+
+
+def test_records_stay_at_their_bound():
+    tr = trace_lib.Tracer()
+    n = trace_lib.RECORDS_MAX + 10
+    for i in range(n):
+        with tr.span("tick", i=i):
+            pass
+    tr.event("last")
+    recs = tr.records
+    assert len(recs) == trace_lib.RECORDS_MAX
+    # the oldest dropped out, the newest are kept in order
+    assert recs[0]["attrs"]["i"] == 11
+    assert recs[-2]["attrs"]["i"] == n - 1 and recs[-1]["name"] == "last"
+
+
+def test_spans_are_profiler_annotations(tmp_path):
+    """Inside a ``jax.profiler`` trace every span is a host-plane event of
+    the same name, nested as the spans were; outside one nothing is
+    built."""
+    from jax.profiler import ProfileData
+
+    tr = trace_lib.Tracer()
+    with trace_lib.profile(str(tmp_path)):
+        with tr.span("outer"):
+            with tr.span("inner"):
+                time.sleep(0.001)
+    path, = tmp_path.rglob("*.xplane.pb")
+    got = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in ("outer", "inner"):
+                    got[ev.name] = (ev.start_ns, ev.start_ns + ev.duration_ns)
+    assert got["outer"][0] <= got["inner"][0] < got["inner"][1] \
+        <= got["outer"][1]
+    assert got["inner"][1] - got["inner"][0] >= 1e6
+    assert tr.spans("inner")[0]["wall_s"] >= 1e-3
 
 
 def test_event_returns_live_attrs_dict():
@@ -70,6 +111,15 @@ def test_metrics_counters_and_summary():
     s = m.summary("lat")
     assert s["count"] == 4 and s["mean"] == 2.5 and s["max"] == 4.0
     assert m.summary("absent")["count"] == 0
+
+
+def test_observe_many_appends_like_observe():
+    m = trace_lib.Metrics()
+    m.observe("lat", 0.5)
+    m.observe_many("lat", np.asarray([1.0, 2.0, 3.0], np.float32))
+    assert m.series("lat") == [0.5, 1.0, 2.0, 3.0]
+    m.observe_many("none", np.zeros(0))
+    assert [s["name"] for s in m.all_summaries()] == ["lat"]
 
 
 def test_export_jsonl_roundtrip(tmp_path):

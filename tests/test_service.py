@@ -116,12 +116,12 @@ class TestTriggerStorm:
         server = ServiceServer(cfg)
         slots = server.admit_sites(demo_batch(8, 1))
         server.step_once()  # compile tick
-        n_spans0 = len(trace.get_tracer().spans("serve.ffr_response"))
+        n_spans0 = len(trace.get_tracer().spans("service.ingest"))
 
         hit = slots[:4]
         for s in hit:
             server.ingest_trigger(s, 49.5)
-        spans = trace.get_tracer().spans("serve.ffr_response")[n_spans0:]
+        spans = trace.get_tracer().spans("service.ingest")[n_spans0:]
         assert len(spans) == len(hit)
         for rec in spans:
             assert rec["wall_s"] * 1e3 < 700.0  # FFR activation budget
@@ -264,3 +264,45 @@ class TestLoadGen:
         trace.metrics.observe("test.p99_series", 1.0)
         s = trace.metrics.summary("test.p99_series")
         assert "p99" in s and s["p99"] == 1.0
+
+
+class TestSpans:
+    def test_tick_spans_nest_and_hold_the_step(self):
+        server = ServiceServer(ServiceConfig(capacity=8, horizon_h=1))
+        slots = server.admit_sites(demo_batch(8, 1))
+        server.step_once()  # compile tick
+        n0 = len(trace.get_tracer().records)
+        for s in slots[:2]:
+            server.ingest_trigger(s, 49.5)
+        out = server.step_once()
+        recs = trace.get_tracer().records[n0:]
+        assert [r["name"] for r in recs] == [
+            "service.ingest", "service.ingest", "service.dispatch",
+            "service.readback", "service.tick"]
+        ingest, dispatch, readback, tick = recs[1:]
+        assert ingest["parent"] is None
+        assert ingest["attrs"]["site"] == slots[1]
+        assert dispatch["parent"] == readback["parent"] == "service.tick"
+        # service.step_ms runs from the dispatch through the read-back,
+        # inside the tick
+        inner = dispatch["wall_s"] + readback["wall_s"]
+        assert inner <= out["step_ms"] * 1e-3 <= tick["wall_s"]
+
+    def test_cli_profile_hook_writes_the_service_spans(self, tmp_path,
+                                                       monkeypatch):
+        import repro.launch.compile_cache as compile_cache
+        from jax.profiler import ProfileData
+
+        from repro.service import server as server_mod
+
+        monkeypatch.setattr(compile_cache, "enable_compile_cache",
+                            lambda: "")
+        monkeypatch.setenv(trace.PROFILE_ENV, str(tmp_path))
+        assert server_mod.main(["--sites", "8", "--horizon-h", "1",
+                                "--ticks", "3"]) == 0
+        path, = tmp_path.rglob("*.xplane.pb")
+        names = {ev.name for plane in ProfileData.from_file(str(path)).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for ev in line.events}
+        assert {"service.tick", "service.dispatch",
+                "service.readback"} <= names
