@@ -10,7 +10,7 @@ Exports resolve lazily (PEP 562) so ``python -m repro.service.server``
 does not import the submodule twice.
 """
 _EXPORTS = {
-    "SiteStore": "state", "StoreState": "state", "SiteStepOut": "state",
+    "SiteStore": "state", "StoreState": "state",
     "ServiceConfig": "server", "ServiceServer": "server",
     "TICK_MAGIC": "server", "encode_tick": "server", "demo_batch": "server",
     "LoadGen": "loadgen", "LoadGenConfig": "loadgen",

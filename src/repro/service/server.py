@@ -20,8 +20,9 @@ An asyncio dispatch loop around a :class:`~repro.service.state.SiteStore`:
     against the 700 ms FFR budget,
   * **the tick** (``service.tick``) advances every resident site with the
     SiteStore's single donated-buffer batched ``engine_step``: its enqueue
-    is ``service.dispatch``, the blocking fetch of the shed/trigger flags
-    ``service.readback``; every span is also a ``jax.profiler``
+    is ``service.dispatch``, the one blocking fetch of the packed
+    trigger/shed flags ``service.readback`` (counted by
+    ``service.fetches``); every span is also a ``jax.profiler``
     annotation, so ``REPRO_JAX_PROFILE_DIR`` (the CLI's profile hook)
     lays them beside the device's programs,
   * **graceful degradation** -- a site whose feed goes stale past
@@ -234,13 +235,14 @@ class ServiceServer:
                      | (self.pending_trig_ns > 0)) & self.slot_active
             enabled = ~self.quarantined
             t0 = time.perf_counter()
-            out = self.store.step(below, enabled)
-            # blocks until the device has run the step
+            flags = self.store.step(below, enabled)
+            # one fetch of both flag rows; blocks until the device has
+            # run the step
             with trace.span("service.readback"):
-                shed = np.asarray(out.shed)
-                trig = np.asarray(out.trig)
+                trig, shed = np.asarray(flags)
             t_done_ns = time.perf_counter_ns()
             step_ms = (time.perf_counter() - t0) * 1e3
+            trace.metrics.inc("service.fetches")
 
             # resolve trigger-to-target: pending triggers consumed by this
             # tick (quarantined lanes stay pending until they rejoin)

@@ -58,29 +58,24 @@ class StoreState(NamedTuple):
     t: jax.Array             # (S,) int32 seconds since admission
 
 
-class SiteStepOut(NamedTuple):
-    """Per-site per-tick outputs the server consumes (all (S,))."""
-
-    trig: jax.Array          # a reserve event triggered this tick
-    shed: jax.Array          # the shed is being served this tick
-    load: jax.Array          # cluster L at the start of the tick
-    it_mw: jax.Array         # site IT power (MW) after the tick
-    tracking_err: jax.Array  # twin tracking error
-
-
 @partial(jax.jit, static_argnames=("cfg", "sched_s"), donate_argnums=(2,))
 def _service_step(cfg: EngineConfig, sched_s: int, st: StoreState,
-                  below, enabled) -> tuple[StoreState, SiteStepOut]:
+                  below, enabled) -> tuple[StoreState, jax.Array]:
     """ONE donated-buffer batched tick over every site lane.
 
     ``below`` is the per-site frequency-below-trigger flag the server
     assembled from its feeds (including island-bypass pending triggers);
     ``enabled`` masks quarantined lanes out of the advance.  The schedule
     tables wrap at ``sched_s`` so an always-on site cycles its horizon.
+
+    Returns the new state and the tick's flags, one ``(2, S)`` bool
+    array -- row 0 ``trig`` (a reserve event triggered this tick), row 1
+    ``shed`` (the shed is being served this tick) -- so the server reads
+    both back in one fetch.
     """
     run = st.active & enabled
 
-    def one(params, lp, es, t, mw, blw, go):
+    def one(params, lp, es, t, blw, go):
         t_sched = jnp.mod(t, sched_s)
         # live demand row: per-second white noise on the shared slow-wave
         # model (the offline block counter cannot be amortised here)
@@ -88,20 +83,16 @@ def _service_step(cfg: EngineConfig, sched_s: int, st: StoreState,
             jax.random.fold_in(lp.fast_key, t), (1,) + lp.mean.shape)
         row = twin_lib.host_loads_rows(
             lp, jnp.asarray(t_sched, jnp.float32)[None], fast)[0]
-        new, (sec, m) = engine_lib.engine_step(
+        new, (sec, _) = engine_lib.engine_step(
             cfg, params, es, (row, blw, go, t_sched))
         # freeze non-running lanes bit-exactly (churn independence)
         new = jax.tree.map(lambda a, b: jnp.where(go, a, b), new, es)
-        out = SiteStepOut(
-            trig=sec.trig & go, shed=sec.shed & go,
-            load=jnp.where(go, sec.load, 0.0),
-            it_mw=jnp.where(go, m.it_power / cfg.design_it_w * mw, 0.0),
-            tracking_err=jnp.where(go, m.tracking_err, 0.0))
-        return new, out
+        return new, (sec.trig & go, sec.shed & go)
 
-    eng, out = jax.vmap(one)(st.params, st.load, st.engine, st.t, st.mw,
-                             below, run)
-    return st._replace(engine=eng, t=st.t + run.astype(jnp.int32)), out
+    eng, (trig, shed) = jax.vmap(one)(st.params, st.load, st.engine, st.t,
+                                      below, run)
+    return (st._replace(engine=eng, t=st.t + run.astype(jnp.int32)),
+            jnp.stack([trig, shed]))
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -240,23 +231,24 @@ class SiteStore:
         self._free.append(slot)
 
     # -- hot path ------------------------------------------------------------
-    def step(self, below=None, enabled=None) -> SiteStepOut:
+    def step(self, below=None, enabled=None) -> jax.Array:
         """One donated-buffer batched tick over every lane.
 
-        ``below``/``enabled`` default to all-clear/all-enabled.  Returns
-        the per-site :class:`SiteStepOut` (device arrays; the caller
-        decides what to fetch)."""
+        ``below``/``enabled`` are ``(S,)`` bool arrays, numpy or device,
+        and default to all-clear/all-enabled.  Returns the tick's
+        ``(2, S)`` bool flags (rows ``trig``, ``shed``), still on the
+        device: the caller fetches them in one transfer."""
         if below is None:
             below = np.zeros((self.capacity,), bool)
         if enabled is None:
             enabled = np.ones((self.capacity,), bool)
-        # the host->device copies and the enqueue: JAX returns before the
-        # device finishes, so the device's share shows in the read-back
+        # the enqueue; the flags go in as they are, so their host->device
+        # copies ride inside the launch.  JAX returns before the device
+        # finishes, so the device's share shows in the read-back
         with trace.span("service.dispatch"):
-            self.state, out = _service_step(
-                self.cfg, self.sched_s, self.state,
-                jnp.asarray(below, bool), jnp.asarray(enabled, bool))
-        return out
+            self.state, flags = _service_step(
+                self.cfg, self.sched_s, self.state, below, enabled)
+        return flags
 
     # -- introspection (tests/bench) ----------------------------------------
     def snapshot(self) -> EngineState:

@@ -101,6 +101,50 @@ class TestHotPath:
         st.step()
         assert st.state.engine.chip_power.unsafe_buffer_pointer() == ptr
 
+    def test_packed_flags_report_trigger_and_shed(self):
+        st, slots = _store(4, 2)
+        st.step()
+        below = np.zeros(4, bool)
+        below[slots[0]] = True
+        enabled = np.ones(4, bool)
+        enabled[slots[1]] = False
+        trig, shed = np.asarray(st.step(below, enabled))
+        assert trig[slots[0]] and shed[slots[0]]
+        assert not trig.any(where=np.arange(4) != slots[0])
+        assert not shed.any(where=np.arange(4) != slots[0])
+        # the shed is served from the trigger's tick on; a disabled lane
+        # below the trigger reports neither flag
+        below[:] = True
+        trig, shed = np.asarray(st.step(below, enabled))
+        assert shed[slots[0]] and not trig[slots[0]]
+        assert not trig[slots[1]] and not shed[slots[1]]
+
+    def test_numpy_and_device_flags_tick_alike(self):
+        below = [np.array([k == 1, False, True, False]) for k in range(4)]
+        enabled = np.array([True, True, False, True])
+        snaps = []
+        for put in (np.asarray, jax.device_put):
+            st, _ = _store(4, 3)
+            SiteStore.clear_step_cache()
+            for b in below:
+                st.step(put(b), put(enabled))
+            assert SiteStore.step_cache_size() == 1
+            snaps.append(st.snapshot())
+        _assert_lanes_equal(*snaps, slice(None),
+                            "numpy and device flags ticked apart")
+
+    def test_tick_returns_the_store_state(self):
+        from repro.core.engine import EngineAccum
+        from repro.service.state import StoreState, _service_step
+
+        st, _ = _store(4, 2)
+        flags = np.zeros(4, bool)
+        new, packed = _service_step(st.cfg, st.sched_s, st.state, flags,
+                                    flags)
+        assert isinstance(new, StoreState)
+        assert isinstance(new.engine.acc, EngineAccum)
+        assert packed.shape == (2, 4) and packed.dtype == bool
+
     def test_admit_validates_capacity_and_horizon(self):
         st, _ = _store(2, 2)
         with pytest.raises(ValueError, match="free slots"):
@@ -108,6 +152,16 @@ class TestHotPath:
         st2 = SiteStore(CFG, 4, 2)
         with pytest.raises(ValueError, match="horizon"):
             st2.admit_batch(demo_batch(1, 1))
+
+
+class TestServerTick:
+    def test_one_fetch_per_tick(self):
+        server = ServiceServer(ServiceConfig(capacity=4, horizon_h=1))
+        server.admit_sites(demo_batch(3, 1))
+        for _ in range(3):
+            n0 = trace.metrics.counters.get("service.fetches", 0)
+            server.step_once()
+            assert trace.metrics.counters["service.fetches"] == n0 + 1
 
 
 class TestTriggerStorm:
