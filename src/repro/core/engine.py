@@ -41,6 +41,15 @@ no ``(N, T, H)`` input buffer exists unless the caller passes a measured
 ``loads=`` override (validated up front; :func:`base_loads` materialises
 the same trace -- identical PRNG bits, float path within 1 ulp).
 
+A batch of a proportional product (``ScenarioBatch.proportional``, the
+Continental FCR-CE) compiles a different scan, chosen statically from
+the host-side batch: its per-second input is the signed droop activation
+``a(t)`` in place of the trigger flag, its tick answers in both
+directions (:func:`repro.core.twin.droop_tick`), and its hourly carry
+sums the required and delivered meter response, which settle per 4-hour
+block (:func:`_rollout_droop_one`).  Triggered batches compile the scan
+they always did.
+
 The scan carry is a flat pytree and every per-scenario input carries a
 leading batch axis, which is what lets ``engine_rollout(mesh=...)`` wrap
 the same vmapped rollout in ``shard_map`` over a ``"scenario"`` mesh
@@ -57,19 +66,22 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 import repro.core.dispatch as dispatch
 import repro.core.plant as plant_lib
+import repro.core.pue as pue_lib
 import repro.core.reserve as reserve
 import repro.core.tier3 as tier3_lib
 import repro.core.twin as twin_lib
 import repro.grid.frequency as frequency
 import repro.grid.markets as markets
 import repro.obs.telemetry as obs_tel
+import repro.obs.trace as obs_trace
 import repro.workload.model as workload_lib
 from repro.grid.scenarios import ScenarioBatch, frequency_seeds, \
-    masked_quantile, scenario_chunk
+    masked_quantile, product_kind, scenario_chunk
 
 
 @dataclass(frozen=True)
@@ -235,30 +247,27 @@ def _hour_params(params: EngineParams, hour) -> HourParams:
         clock_w=params.clock_w)
 
 
-def _engine_tick(cfg: EngineConfig, hp: HourParams, state: EngineState, xs):
-    """The fused 1 Hz tick body with the hour's scalars already gathered."""
-    base_load, below, in_hor, t = xs
-    (in_ev, hold), trig, shed = reserve.detection_step(
-        (state.in_event, state.hold), below, in_hor, hp.min_dur_i)
-
-    load_h = base_load * hp.mu / 0.9
+def _demand(cfg: EngineConfig, base_load, mu, t):
+    """The hosts' demand at the hour's operating fraction."""
+    load_h = base_load * mu / 0.9
     if cfg.step_transient_amp:
         # step-synchronous power wave (EasyRider): gated on the STATIC
         # amplitude so the default-0 graph is unchanged (the parity path)
         load_h = jnp.clip(
             load_h * workload_lib.step_transient(
                 t, cfg.step_period_s, cfg.step_transient_amp), 0.0, 1.0)
-    carry = (state.rls, state.chip_power, state.caps, state.key)
-    (rls, chip_power, caps, key), m = twin_lib.twin_tick(
-        cfg.n_hosts, cfg.chips_per_host, cfg.chip_tdp, hp.pue_design,
-        carry, load_h, hp.mu, hp.rho, shed, hp.t_amb)
+    return load_h
 
+
+def _accumulate(cfg: EngineConfig, a: EngineAccum, m, in_hor, t, shed,
+                shed_it_rate, clock_w):
+    """(L, running sums) after one tick: ``shed`` marks a second of shed
+    and ``shed_it_rate`` the IT-side band it shed."""
     L = m.it_power / cfg.design_it_w
     g = in_hor.astype(jnp.float32)
     w = g * (t >= cfg.warmup_s)
     design_host = cfg.chips_per_host * cfg.chip_tdp
-    a = state.acc
-    acc = EngineAccum(
+    return L, EngineAccum(
         n_s=a.n_s + g,
         n_warm=a.n_warm + w,
         err=a.err + w * jnp.mean(m.ar4_abs_err) / design_host,
@@ -268,16 +277,120 @@ def _engine_tick(cfg: EngineConfig, hp: HourParams, state: EngineState, xs):
         chip_mean=a.chip_mean + g * m.chip_power_mean,
         chip_p95=a.chip_p95 + g * m.chip_power_p95,
         shed_s=a.shed_s + shed.astype(jnp.float32),
-        shed_it=a.shed_it + hp.rho_it * shed,
+        shed_it=a.shed_it + shed_it_rate * shed,
         # realised workload throughput at this second's cluster power
         # fraction -- the per-chip budget the fleet actually ran at --
         # through the shared DVFS/duty-cycle curve
-        thr=a.thr + g * workload_lib.throughput_frac(hp.clock_w, L),
+        thr=a.thr + g * workload_lib.throughput_frac(clock_w, L),
     )
+
+
+def _engine_tick(cfg: EngineConfig, hp: HourParams, state: EngineState, xs):
+    """The fused 1 Hz tick body with the hour's scalars already gathered."""
+    base_load, below, in_hor, t = xs
+    (in_ev, hold), trig, shed = reserve.detection_step(
+        (state.in_event, state.hold), below, in_hor, hp.min_dur_i)
+
+    load_h = _demand(cfg, base_load, hp.mu, t)
+    carry = (state.rls, state.chip_power, state.caps, state.key)
+    (rls, chip_power, caps, key), m = twin_lib.twin_tick(
+        cfg.n_hosts, cfg.chips_per_host, cfg.chip_tdp, hp.pue_design,
+        carry, load_h, hp.mu, hp.rho, shed, hp.t_amb)
+
+    L, acc = _accumulate(cfg, state.acc, m, in_hor, t, shed, hp.rho_it,
+                         hp.clock_w)
     sec = EngineSecond(trig=trig, shed=shed, load=state.last_load)
     new = EngineState(rls=rls, chip_power=chip_power, caps=caps, key=key,
                       last_load=L, in_event=in_ev, hold=hold, acc=acc)
     return new, (sec, m)
+
+
+class DroopHour(NamedTuple):
+    """One hour's scalars of a proportional product's tick.  In the
+    rollout's table the hourly leaves are (Hm,) and the rest scalars;
+    the outer scan takes each hour's row once."""
+
+    mu: jax.Array
+    rho: jax.Array
+    t_amb: jax.Array
+    band_dn: jax.Array      # IT-side band under-frequency (a > 0)
+    band_up: jax.Array      # IT-side band over-frequency (a < 0)
+    declared: jax.Array     # declared facility power, per unit design IT
+    pue_design: jax.Array
+    clock_w: jax.Array
+    scale: jax.Array        # twin.site_scale of the scenario's site
+
+
+class FcrHour(NamedTuple):
+    """One hour's sums of a proportional product's response, per unit of
+    design IT power times seconds (the droop scan's inner carry, reset
+    each hour and stacked by the outer scan)."""
+
+    req_dn: jax.Array       # required meter response, under-frequency
+    req_up: jax.Array       # |required| over-frequency
+    dlv_dn: jax.Array       # delivered (declared - meter), under
+    dlv_up: jax.Array       # |delivered| over-frequency
+    abs_err: jax.Array      # |delivered - required| over active seconds
+    active_s: jax.Array     # seconds with a != 0
+    up_s: jax.Array         # seconds with a < 0
+
+
+def _fcr_hour_init() -> FcrHour:
+    z = jnp.zeros((), jnp.float32)
+    return FcrHour(*([z] * len(FcrHour._fields)))
+
+
+def _declared_fac(cfg: EngineConfig, mu_h, t_amb, pue_design):
+    """(Hm,) facility power, per unit of design IT, that a site selling a
+    proportional product declares as its baseline for each hour: at the
+    hour's mu, every host's long-run mean demand
+    (``twin.host_mean_demand``, scaled as :func:`_demand` scales it)
+    through the power model, each chip held to its share of the envelope,
+    then the meter.  It needs only the schedule and the workload's
+    archetypes, so it is known before the day starts."""
+    mean = twin_lib.host_mean_demand(cfg.n_hosts)
+    load = jnp.clip(mean * mu_h[:, None] / 0.9, 0.0, 1.0)       # (Hm, H)
+    cap = jnp.clip(mu_h[:, None] * cfg.chip_tdp, plant_lib.CAP_MIN,
+                   plant_lib.CAP_MAX)
+    chip = jnp.minimum(plant_lib.power_model(plant_lib.F_NOMINAL, load), cap)
+    it = jnp.mean(chip, axis=-1) / cfg.chip_tdp
+    return it * pue_lib.pue(it, t_amb, pue_design=pue_design)
+
+
+def _droop_tick(cfg: EngineConfig, hp: DroopHour, state: EngineState,
+                fh: FcrHour, xs):
+    """The 1 Hz tick of a proportional product: the site's demand, the
+    droop twin tick, the running sums, and the hour's response sums.
+    Returns (state, fh, metrics of the second)."""
+    base_load, act, in_hor, t = xs
+    demand = twin_lib.site_demand(
+        base_load, twin_lib.host_mean_demand(cfg.n_hosts), hp.scale)
+    load_h = _demand(cfg, demand, hp.mu, t)
+    carry = (state.rls, state.chip_power, state.caps, state.key)
+    (rls, chip_power, caps, key), m = twin_lib.droop_tick(
+        cfg.n_hosts, cfg.chips_per_host, cfg.chip_tdp, hp.pue_design,
+        carry, load_h, hp.mu, hp.band_dn, hp.band_up, act, hp.t_amb,
+        hp.scale)
+    shed = (act > 0) & in_hor
+    L, acc = _accumulate(cfg, state.acc, m, in_hor, t, shed,
+                         hp.band_dn * act, hp.clock_w)
+    with jax.named_scope("engine.fcr_blocks"):
+        g = in_hor.astype(jnp.float32)
+        dn = g * (act > 0)
+        up = g * (act < 0)
+        on = dn + up
+        req = hp.rho * hp.pue_design * act
+        dlv = hp.declared - m.facility_power / cfg.design_it_w
+        fh = FcrHour(req_dn=fh.req_dn + dn * req,
+                     req_up=fh.req_up - up * req,
+                     dlv_dn=fh.dlv_dn + dn * dlv,
+                     dlv_up=fh.dlv_up - up * dlv,
+                     abs_err=fh.abs_err + on * jnp.abs(dlv - req),
+                     active_s=fh.active_s + on,
+                     up_s=fh.up_s + up)
+    new = state._replace(rls=rls, chip_power=chip_power, caps=caps, key=key,
+                         last_load=L, acc=acc)
+    return new, fh, m
 
 
 def engine_step(cfg: EngineConfig, params: EngineParams, state: EngineState,
@@ -309,14 +422,16 @@ def engine_step(cfg: EngineConfig, params: EngineParams, state: EngineState,
 
 
 def _hourly_one(cfg: EngineConfig, ci, t_amb, mask, mw, pue_design,
-                product_idx, rho_batch, mix_idx, ops=None) -> dict:
+                product_idx, rho_batch, mix_idx, ops=None,
+                symmetric: bool = False) -> dict:
     """Tier-3 grid search + hourly schedule energy/carbon accounting.
 
     ``ops`` overrides the in-graph grid search with externally committed
     hourly trajectories: a ``(mu_h, rho_h)`` pair of (H_max,) arrays (the
     differentiable bidder's output replayed through the real settlement).
     The ``None`` default is a static Python branch, so every existing
-    caller keeps the exact pre-override graph.
+    caller keeps the exact pre-override graph.  ``symmetric`` (static)
+    searches for a proportional product (``tier3.headroom_ok``).
     """
     clock_w = jnp.asarray(workload_lib.CLOCK_W)[mix_idx]
     if ops is None:
@@ -330,7 +445,8 @@ def _hourly_one(cfg: EngineConfig, ci, t_amb, mask, mw, pue_design,
             rho_fixed=rho_batch, clock_w=clock_w, ckpt_cost_s=cfg.ckpt_cost_s,
             use_revenue=cfg.price_aware,
             fix_rho=(cfg.rho_mode == "batch"),
-            use_workload=(cfg.workload_weight != 0.0))
+            use_workload=(cfg.workload_weight != 0.0),
+            symmetric=symmetric)
         mu_sel, rho_sel = op.mu, op.rho
     else:
         mu_sel, rho_sel = ops
@@ -357,6 +473,60 @@ def _hourly_one(cfg: EngineConfig, ci, t_amb, mask, mw, pue_design,
         # hours -> millions of tokens at the mix's site rate
         sched_tokens_mtok=energy["thr"] * 3600.0 * mw * tok_rate / 1e6,
     )
+
+
+def _scan_inputs(cfg: EngineConfig, per_second, valid_s, base_loads,
+                 load_key):
+    """The hierarchical scan's inputs: (load synthesis constants or None,
+    xs), with the (T,) per-second input, the horizon gate and (with a
+    ``base_loads`` override) the demand rows blocked into (B, K) hours."""
+    T = per_second.shape[-1]
+    K = twin_lib.LOAD_BLOCK_S
+    B = T // K
+    flags_b = per_second.reshape(B, K)
+    in_hor_b = (jnp.arange(T, dtype=jnp.int32) < valid_s).reshape(B, K)
+    hours_idx = jnp.arange(B, dtype=jnp.int32)
+    lp = (twin_lib.host_load_params(cfg.n_hosts, load_key)
+          if base_loads is None else None)
+    xs = ((flags_b, in_hor_b, hours_idx) if base_loads is None else
+          (base_loads.reshape(B, K, -1), flags_b, in_hor_b, hours_idx))
+    return lp, xs
+
+
+def _hour_rows(lp, xb):
+    """One hour of the scan inputs: (demand rows, per-second input,
+    horizon gate, hour index); the rows come from the counter-based PRNG
+    unless the caller passed them."""
+    if lp is not None:
+        flags_r, in_r, b = xb
+        return twin_lib.host_loads_block(lp, b), flags_r, in_r, b
+    return xb
+
+
+def _site_outputs(acc: EngineAccum, mw, mix_idx, clock_w):
+    """The twin's and the workload's outputs of a rollout's running sums
+    (streaming aggregates; site-MW energies; millions of tokens), shared
+    by both scans.  Earned tokens integrate the realised per-second
+    throughput; the reference runs every valid second at the top of the
+    mu grid.  Returns (outputs, reference throughput, Mtok per
+    throughput-second)."""
+    n = jnp.maximum(acc.n_s, 1.0)
+    nw = jnp.maximum(acc.n_warm, 1.0)
+    tok_rate = jnp.asarray(workload_lib.TOKENS_PER_MW_S)[mix_idx]
+    thr_ref = workload_lib.throughput_frac(
+        clock_w, float(tier3_lib.MU_GRID[-1]))
+    tok_unit = mw * tok_rate / 1e6
+    return dict(
+        ar4_mae_norm=acc.err / nw,
+        tracking_err_mean=acc.track / nw,
+        chip_power_mean=acc.chip_mean / n,
+        chip_power_p95=acc.chip_p95 / n,
+        it_mwh=acc.load * mw / 3600.0,
+        fac_mwh=acc.fac * mw / 3600.0,
+        shed_it_mwh=acc.shed_it * mw / 3600.0,
+        thr_mean=acc.thr / n,
+        tokens_mtok=acc.thr * tok_unit,
+    ), thr_ref, tok_unit
 
 
 def _rollout_one(cfg: EngineConfig, reduce: str, ci, t_amb, mask, hours,
@@ -390,23 +560,12 @@ def _rollout_one(cfg: EngineConfig, reduce: str, ci, t_amb, mask, hours,
     # normal per hour, ~30 % cheaper than per-tick draws inside the
     # body), so peak input memory stays O(H) per scenario per hour.
     K = twin_lib.LOAD_BLOCK_S
-    B = T // K
-    below_b = (freq < trig_hz).reshape(B, K)
-    in_hor_b = (jnp.arange(T, dtype=jnp.int32) < valid_s).reshape(B, K)
-    hours_idx = jnp.arange(B, dtype=jnp.int32)
-    lp = (twin_lib.host_load_params(cfg.n_hosts, load_key)
-          if base_loads is None else None)
-    xs = ((below_b, in_hor_b, hours_idx) if base_loads is None else
-          (base_loads.reshape(B, K, -1), below_b, in_hor_b, hours_idx))
+    lp, xs = _scan_inputs(cfg, freq < trig_hz, valid_s, base_loads, load_key)
 
     design_host = cfg.chips_per_host * cfg.chip_tdp
 
     def hour_body(state, xb):
-        if base_loads is None:
-            below_r, in_r, b = xb
-            loads_r = twin_lib.host_loads_block(lp, b)
-        else:
-            loads_r, below_r, in_r, b = xb
+        loads_r, below_r, in_r, b = _hour_rows(lp, xb)
         hp = _hour_params(params, b)
         t_row = b * K + jnp.arange(K, dtype=jnp.int32)
 
@@ -470,38 +629,21 @@ def _rollout_one(cfg: EngineConfig, reduce: str, ci, t_amb, mask, hours,
     penalty_eur = reserve.event_clawback(
         events, price * committed_h[hour_ev] * tier3_lib.PENALTY_WINDOW_H)
 
+    # --- workload settlement: each event additionally charges the
+    #     checkpoint+restore dead time at the reference rate.
     acc = state.acc
-    n = jnp.maximum(acc.n_s, 1.0)
-    nw = jnp.maximum(acc.n_warm, 1.0)
-
-    # --- workload settlement: lost training tokens alongside energy and
-    #     reserve revenue.  Earned tokens integrate the realised per-second
-    #     throughput; the reference runs every valid second at the top of
-    #     the mu grid; each event additionally charges the checkpoint+
-    #     restore dead time at the reference rate.
-    tok_rate = jnp.asarray(workload_lib.TOKENS_PER_MW_S)[mix_idx]
+    site, thr_ref, tok_unit = _site_outputs(acc, mw, mix_idx, clock_w)
     n_events_f = jnp.sum(valid).astype(jnp.float32)
-    thr_ref = workload_lib.throughput_frac(
-        clock_w, float(tier3_lib.MU_GRID[-1]))
-    tok_unit = mw * tok_rate / 1e6                     # Mtok per thr-second
-    tokens_mtok = acc.thr * tok_unit
     tokens_ckpt_mtok = n_events_f * cfg.ckpt_cost_s * thr_ref * tok_unit
     tokens_ref_mtok = acc.n_s * thr_ref * tok_unit
 
     out.update(
-        # twin summary (streaming aggregates; site-MW energies)
-        ar4_mae_norm=acc.err / nw,
-        tracking_err_mean=acc.track / nw,
-        chip_power_mean=acc.chip_mean / n,
-        chip_power_p95=acc.chip_p95 / n,
-        it_mwh=acc.load * mw / 3600.0,
-        fac_mwh=acc.fac * mw / 3600.0,
+        site,
         # reserve replay + settlement
         events=events,
         events_sched=events_sched,
         n_events=jnp.sum(valid).astype(jnp.int32),
         active_s=acc.shed_s.astype(jnp.int32),
-        shed_it_mwh=acc.shed_it * mw / 3600.0,
         committed_mw=jnp.sum(committed_h * mask)
         / jnp.maximum(jnp.sum(mask), 1.0),
         capacity_eur=capacity_eur,
@@ -509,10 +651,9 @@ def _rollout_one(cfg: EngineConfig, reduce: str, ci, t_amb, mask, hours,
         net_eur=capacity_eur - penalty_eur,
         n_compliant=jnp.sum(valid & events.compliant).astype(jnp.int32),
         # workload settlement (millions of tokens)
-        thr_mean=acc.thr / n,
-        tokens_mtok=tokens_mtok,
         tokens_ckpt_mtok=tokens_ckpt_mtok,
-        tokens_lost_mtok=tokens_ref_mtok - tokens_mtok + tokens_ckpt_mtok,
+        tokens_lost_mtok=tokens_ref_mtok - site["tokens_mtok"]
+        + tokens_ckpt_mtok,
     )
     if cfg.telemetry:
         out["telemetry"] = obs_tel.finalize(
@@ -528,12 +669,142 @@ def _rollout_one(cfg: EngineConfig, reduce: str, ci, t_amb, mask, hours,
     return out
 
 
+def _rollout_droop_one(cfg: EngineConfig, reduce: str, ci, t_amb, mask,
+                       hours, mw, pue_design, product_idx, rho_batch,
+                       mix_idx, freq, base_loads, load_key, key,
+                       ops=None) -> dict:
+    """One scenario of a proportional product (FCR-CE): the symmetric
+    Tier-3 search, the droop scan, and per-block verdicts and settlement.
+
+    Every second the site answers ``a(t)`` (``reserve.droop_activation``
+    under ``markets.DROOP``); the required response is ``rho * MW *
+    PUE_design * a`` at the meter, the delivered one the hour's declared
+    baseline (:func:`_declared_fac`) less the meter.  The simulated hosts
+    stand for the site's population of hosts (``twin.site_demand``,
+    ``twin.site_scale``).  The response sums settle per block of
+    ``DROOP.block_h`` hours: a block whose mean |delivered - required|
+    over its active seconds passes ``tracking_tol`` times the committed
+    meter MW forfeits the block's capacity payment.  Summary mode emits no
+    per-second output.
+    """
+    droop = markets.DROOP
+    out = _hourly_one(cfg, ci, t_amb, mask, mw, pue_design, product_idx,
+                      rho_batch, mix_idx, ops, symmetric=True)
+    mu_h, rho_h = out["mu_h"], out["rho_h"]
+    clock_w = jnp.asarray(workload_lib.CLOCK_W)[mix_idx]
+    T = freq.shape[-1]
+    h_max = mu_h.shape[-1]
+    valid_s = jnp.asarray(hours, jnp.int32) * 3600
+    band_dn, band_up = tier3_lib.droop_bands(
+        mu_h, t_amb, rho_h, pue_design, pue_aware=cfg.pue_aware)
+    declared = _declared_fac(cfg, mu_h, t_amb, pue_design)
+    table = DroopHour(
+        mu=mu_h, rho=rho_h, t_amb=t_amb, band_dn=band_dn, band_up=band_up,
+        declared=declared, pue_design=pue_design, clock_w=clock_w,
+        scale=twin_lib.site_scale(cfg.n_hosts * cfg.chips_per_host,
+                                  cfg.chip_tdp, mw))
+    K = twin_lib.LOAD_BLOCK_S
+    with jax.named_scope("engine.droop"):
+        act = reserve.droop_activation(freq, droop.deadband_hz,
+                                       droop.full_activation_hz)
+    lp, xs = _scan_inputs(cfg, act, valid_s, base_loads, load_key)
+
+    def hour_body(state, xb):
+        loads_r, act_r, in_r, b = _hour_rows(lp, xb)
+        hr = jnp.minimum(b, h_max - 1)
+        hp = jax.tree.map(lambda x: x[hr] if x.ndim else x, table)
+        t_row = b * K + jnp.arange(K, dtype=jnp.int32)
+
+        def tick(carry, x):
+            st, fh, m = _droop_tick(cfg, hp, *carry, x)
+            return (st, fh), (m if reduce == "full" else None)
+
+        (state, fh), ys = jax.lax.scan(
+            tick, (state, _fcr_hour_init()), (loads_r, act_r, in_r, t_row),
+            unroll=cfg.unroll)
+        return state, (ys, fh)
+
+    state, (ys, fh) = jax.lax.scan(hour_body, engine_init(cfg, key), xs)
+
+    with jax.named_scope("engine.fcr_blocks"):
+        price = jnp.asarray(markets.CAPACITY_PRICE_EUR_MW_H)[product_idx]
+        committed_h = rho_h * mw * pue_design              # (Hm,) meter MW
+        capacity_h = price * committed_h * mask
+
+        def blocks(x):
+            return reserve.to_blocks(x, droop.block_h)
+
+        hours_b = blocks(mask)
+        valid_b = hours_b > 0
+        committed_b = blocks(committed_h * mask) / jnp.maximum(hours_b, 1.0)
+        err_b = blocks(fh.abs_err) * mw                    # MW s
+        active_b = blocks(fh.active_s)
+        ok_b = reserve.block_verdicts(err_b, active_b, committed_b,
+                                      droop.tracking_tol)
+        capacity_eur = jnp.sum(capacity_h)
+        penalty_eur = reserve.block_clawback(ok_b, valid_b,
+                                             blocks(capacity_h))
+
+    acc = state.acc
+    site, thr_ref, tok_unit = _site_outputs(acc, mw, mix_idx, clock_w)
+    mwh = mw / 3600.0                      # per-unit seconds -> site MWh
+    out.update(
+        site,
+        # the droop's response and its settlement
+        declared_mw_h=declared * mw * mask,
+        active_s=jnp.sum(fh.active_s).astype(jnp.int32),
+        up_s=jnp.sum(fh.up_s).astype(jnp.int32),
+        req_dn_mwh=jnp.sum(fh.req_dn) * mwh,
+        req_up_mwh=jnp.sum(fh.req_up) * mwh,
+        dlv_dn_mwh=jnp.sum(fh.dlv_dn) * mwh,
+        dlv_up_mwh=jnp.sum(fh.dlv_up) * mwh,
+        block_ok=ok_b & valid_b,
+        block_valid=valid_b,
+        block_err_mw=jnp.where(valid_b,
+                               err_b / jnp.maximum(active_b, 1.0), 0.0),
+        n_blocks=jnp.sum(valid_b).astype(jnp.int32),
+        n_blocks_failed=jnp.sum(valid_b & ~ok_b).astype(jnp.int32),
+        committed_mw=jnp.sum(committed_h * mask)
+        / jnp.maximum(jnp.sum(mask), 1.0),
+        capacity_eur=capacity_eur,
+        penalty_eur=penalty_eur,
+        net_eur=capacity_eur - penalty_eur,
+        # workload: the droop duty-scales, it never checkpoints
+        tokens_lost_mtok=acc.n_s * thr_ref * tok_unit - site["tokens_mtok"],
+    )
+    if reduce == "full":
+        out.update(metrics=jax.tree.map(
+            lambda a: a.reshape((T,) + a.shape[2:]), ys), act=act)
+    return out
+
+
+# the device-computed counts of a proportional rollout, published to the
+# tracer's metrics by publish_fcr_counters: counter name -> output key
+FCR_COUNTERS = {"fcr.active_s": "active_s", "fcr.up_s": "up_s",
+                "fcr.blocks": "n_blocks",
+                "fcr.blocks_failed": "n_blocks_failed"}
+
+
+def publish_fcr_counters(out: dict) -> dict:
+    """Add the FCR counts of a proportional rollout's (or finalized
+    sweep's) outputs to ``repro.obs.trace.metrics``.  The counts are
+    computed on the device inside the rollout; reading them is this
+    call's one host sync, which is why the rollout does not make it.
+    Returns the added values."""
+    got = {name: float(np.sum(np.asarray(out[k])))
+           for name, k in FCR_COUNTERS.items()}
+    for name, v in got.items():
+        obs_trace.metrics.inc(name, v)
+    return got
+
+
 def _engine_seconds_vmapped(cfg: EngineConfig, reduce: str,
                             batch: ScenarioBatch, freq, base_loads,
                             load_keys, scan_keys, ops=None) -> dict:
     # ops=None is an empty pytree, so the uniform in_axes=0 maps it (and a
     # None base_loads) trivially; an (N, H_max) ops pair maps per scenario.
-    fn = partial(_rollout_one, cfg, reduce)
+    fn = partial(_rollout_droop_one if batch.proportional else _rollout_one,
+                 cfg, reduce)
     return jax.vmap(fn)(batch.ci, batch.t_amb, batch.mask, batch.hours,
                         batch.mw, batch.pue_design, batch.product_idx,
                         batch.reserve_rho, batch.mix_idx, freq, base_loads,
@@ -550,7 +821,7 @@ def _engine_seconds_jit(cfg: EngineConfig, reduce: str, batch: ScenarioBatch,
 
 def _engine_hourly_vmapped(cfg: EngineConfig, batch: ScenarioBatch,
                            ops=None) -> dict:
-    fn = partial(_hourly_one, cfg)
+    fn = partial(_hourly_one, cfg, symmetric=batch.proportional)
     return jax.vmap(fn)(batch.ci, batch.t_amb, batch.mask, batch.mw,
                         batch.pue_design, batch.product_idx,
                         batch.reserve_rho, batch.mix_idx, ops)
@@ -758,6 +1029,11 @@ def engine_rollout(cfg: EngineConfig, batch: ScenarioBatch, *,
     ``repro.obs.telemetry``); leaves stay (N,), (N, H_max), (N, B) or
     (N, e_max), so summary mode keeps its O(N*H + N*B) output bound.
 
+    A proportional batch (FCR-CE) returns the droop's response sums,
+    per-block verdicts and block settlement in place of the event buffers
+    (:func:`_rollout_droop_one`); :func:`publish_fcr_counters` adds its
+    counts to the tracer's metrics once the caller holds the outputs.
+
     ``mesh`` shards the sweep over devices: pass a Mesh with a
     ``"scenario"`` axis (see ``repro.launch.mesh.resolve_mesh``) or
     ``"auto"`` for a 1-D mesh over every local device.  The batch is
@@ -790,11 +1066,14 @@ def engine_rollout(cfg: EngineConfig, batch: ScenarioBatch, *,
         fn = _sharded_hourly_fn(cfg, mesh, ops is not None)
         return unpad_scenario_axis(fn(padded, ops_p), n)
     n, T = batch.n, int(batch.h_max) * 3600
+    if batch.proportional and cfg.telemetry:
+        raise ValueError("telemetry taps the triggered products' events; "
+                         "a proportional batch has none")
     if freq is None:
         freq, _ = frequency.synthesize_frequency_batch(
             frequency_seeds(batch), batch.product_idx, n_seconds=T,
             events_per_day=cfg.events_per_day,
-            max_events=cfg.max_freq_events)
+            max_events=cfg.max_freq_events, proportional=batch.proportional)
     elif freq.shape != (n, T):
         raise ValueError(
             f"freq override must have shape (N, T) = ({n}, {T}) = "
@@ -842,8 +1121,20 @@ _SWEEP_SECONDS_SUMS = ("it_mwh", "fac_mwh", "shed_it_mwh", "active_s",
                        "tokens_ckpt_mtok", "tokens_lost_mtok")
 
 
-def summary_init(cfg: EngineConfig) -> dict:
-    """The monoid identity: the aggregate of zero scenarios.
+# the same for a proportional product's seconds tier
+_SWEEP_DROOP_SUMS = ("it_mwh", "fac_mwh", "shed_it_mwh", "active_s", "up_s",
+                     "req_dn_mwh", "req_up_mwh", "dlv_dn_mwh", "dlv_up_mwh",
+                     "n_blocks", "n_blocks_failed", "capacity_eur",
+                     "penalty_eur", "net_eur", "tokens_mtok",
+                     "tokens_lost_mtok")
+_SWEEP_TWIN_SUMS = ("seconds", "warm_s", "ar4_err_s", "track_err_s",
+                    "chip_mean_s", "chip_p95_s", "thr_s",
+                    "committed_mw_hours")
+
+
+def summary_init(cfg: EngineConfig, proportional: bool = False) -> dict:
+    """The monoid identity: the aggregate of zero scenarios (of a
+    triggered or a ``proportional`` product).
 
     Every leaf is float32 (counts included) so the donated aggregate
     buffer keeps one dtype across merges; extremes start at -/+inf and
@@ -855,12 +1146,13 @@ def summary_init(cfg: EngineConfig) -> dict:
                         "cfe_mu_hours") + _SWEEP_SCHED_SUMS}
     if not cfg.with_seconds:
         return s
-    s.update({k: z for k in ("seconds", "warm_s", "ar4_err_s",
-                             "track_err_s", "chip_mean_s", "chip_p95_s",
-                             "thr_s", "committed_mw_hours",
-                             "n_compliant_sched", "ev_delivered_frac_sum",
-                             "ev_t_full_ms_sum", "ev_budget_ok",
-                             "ev_sustain_ok", "ev_delivered_ok")
+    if proportional:
+        s.update({k: z for k in _SWEEP_TWIN_SUMS + _SWEEP_DROOP_SUMS})
+        return s
+    s.update({k: z for k in _SWEEP_TWIN_SUMS
+              + ("n_compliant_sched", "ev_delivered_frac_sum",
+                 "ev_t_full_ms_sum", "ev_budget_ok", "ev_sustain_ok",
+                 "ev_delivered_ok")
               + _SWEEP_SECONDS_SUMS})
     s["ev_t_full_ms_max"] = neg
     if cfg.telemetry:
@@ -920,6 +1212,10 @@ def chunk_summary(cfg: EngineConfig, out: dict, batch: ScenarioBatch,
         thr_s=jnp.sum(lane * out["thr_mean"] * nc),
         committed_mw_hours=jnp.sum(lane * out["committed_mw"] * hv),
     )
+    if batch.proportional:
+        for k in _SWEEP_DROOP_SUMS:
+            s[k] = jnp.sum(lane * out[k].astype(jnp.float32))
+        return s
     for k in _SWEEP_SECONDS_SUMS:
         s[k] = jnp.sum(lane * out[k].astype(jnp.float32))
     ev = out["events"]
@@ -998,7 +1294,6 @@ def sweep_finalize(agg: dict) -> dict:
         return out
     sec = max(float(a["seconds"]), 1.0)
     warm = max(float(a["warm_s"]), 1.0)
-    n_ev = max(float(a["n_events"]), 1.0)
     out.update(
         seconds=float(a["seconds"]),
         ar4_mae_norm=float(a["ar4_err_s"]) / warm,
@@ -1006,7 +1301,14 @@ def sweep_finalize(agg: dict) -> dict:
         chip_power_mean=float(a["chip_mean_s"]) / sec,
         chip_power_p95=float(a["chip_p95_s"]) / sec,
         thr_mean=float(a["thr_s"]) / sec,
-        committed_mw=float(a["committed_mw_hours"]) / hv,
+        committed_mw=float(a["committed_mw_hours"]) / hv)
+    if "n_blocks" in a:                       # a proportional product
+        out.update({k: float(a[k]) for k in _SWEEP_DROOP_SUMS})
+        out["block_compliance"] = 1.0 - out["n_blocks_failed"] / max(
+            out["n_blocks"], 1.0)
+        return out
+    n_ev = max(float(a["n_events"]), 1.0)
+    out.update(
         compliance=float(a["n_compliant"]) / n_ev,
         compliance_sched=float(a["n_compliant_sched"]) / n_ev,
         delivered_frac_mean=float(a["ev_delivered_frac_sum"]) / n_ev,
@@ -1047,7 +1349,8 @@ def _sweep_body(cfg: EngineConfig, batch: ScenarioBatch, lane) -> dict:
     T = int(batch.h_max) * 3600
     freq, _ = frequency.synthesize_frequency_batch(
         frequency_seeds(batch), batch.product_idx, n_seconds=T,
-        events_per_day=cfg.events_per_day, max_events=cfg.max_freq_events)
+        events_per_day=cfg.events_per_day, max_events=cfg.max_freq_events,
+        proportional=batch.proportional)
     load_keys, scan_keys = _scenario_keys_jit(jnp.asarray(batch.seed))
     out = _engine_seconds_vmapped(cfg, "summary", batch, freq, None,
                                   load_keys, scan_keys)
@@ -1132,7 +1435,10 @@ def engine_sweep(cfg: EngineConfig, specs, *, chunk_size: int, mesh=None,
     horizon -- computed from specs without building any batch).
     ``progress(chunks_done, n_chunks)`` is called after each folded
     chunk.  Returns :func:`sweep_finalize` metrics, or the raw aggregate
-    dict when ``finalize=False``.
+    dict when ``finalize=False``.  The specs sell one kind of product
+    (``scenarios.product_kind``); a proportional sweep publishes its FCR
+    counts (:func:`publish_fcr_counters`) from the aggregate it reads
+    back at the end.
     """
     from repro.launch import mesh as mesh_lib
     if chunk_size < 1:
@@ -1146,17 +1452,27 @@ def engine_sweep(cfg: EngineConfig, specs, *, chunk_size: int, mesh=None,
         n_dev = mesh.shape[_SCENARIO_AXIS]
     if h_max is None:
         h_max = max(s.horizon_h for s in specs)
+    proportional = product_kind(specs)
+    if proportional and cfg.telemetry:
+        raise ValueError("telemetry taps the triggered products' events; "
+                         "a proportional sweep has none")
     lo0, hi0 = mesh_lib.process_slice(len(specs))
     pad_to = (chunk_size if n_dev is None
               else -(-chunk_size // n_dev) * n_dev)
-    # .copy() forces one distinct device buffer per leaf: jax caches
-    # equal scalar constants, and donating an aliased buffer twice in
-    # one step is an error
-    agg = jax.tree.map(lambda x: jnp.asarray(x).copy(), summary_init(cfg))
-    if mesh is not None:
-        # materialised per-device lanes (donation needs real buffers)
-        agg = jax.tree.map(
-            lambda x: jnp.tile(x[None], (n_dev,) + (1,) * x.ndim), agg)
+    agg = summary_init(cfg, proportional)
+    if mesh is None:
+        # .copy() forces one distinct device buffer per leaf: jax caches
+        # equal scalar constants, and donating an aliased buffer twice in
+        # one step is an error
+        agg = jax.tree.map(lambda x: jnp.asarray(x).copy(), agg)
+    else:
+        # materialised per-device lanes (donation needs real buffers), laid
+        # out over the mesh as the step returns them, so the first chunk
+        # runs the same compiled program as every later one
+        lanes = NamedSharding(mesh, P(_SCENARIO_AXIS))
+        agg = jax.tree.map(lambda x: jax.device_put(
+            np.tile(np.asarray(x)[None], (n_dev,) + (1,) * np.ndim(x)),
+            lanes), agg)
         step = _sweep_step_sharded(cfg, mesh)
     starts = range(lo0, hi0, chunk_size)
     for i, lo in enumerate(starts):
@@ -1176,6 +1492,8 @@ def engine_sweep(cfg: EngineConfig, specs, *, chunk_size: int, mesh=None,
             merged = summary_merge(
                 merged, jax.tree.map(lambda x, d=d: x[d], host))
         host = jax.tree.map(np.asarray, merged)
+    if proportional and cfg.with_seconds:
+        publish_fcr_counters(host)
     return sweep_finalize(host) if finalize else host
 
 

@@ -108,3 +108,20 @@ def ffr_meter_gain(mu, rho, t_amb, *, pue_design: float = PUE_DESIGN):
     lo = facility_power(jnp.maximum(mu - rho, 0.02), 1.0, t_amb,
                         pue_design=pue_design)
     return (hi - lo) / rho
+
+
+def meter_gain_up(mu, rho, t_amb, *, pue_design: float = PUE_DESIGN):
+    """Meter-side delivery per unit of IT-side band *raised* from ``mu``:
+
+        [F(mu + rho) - F(mu)] / (rho * P_design)
+
+    the up-regulation side of :func:`ffr_meter_gain`.  A symmetric
+    proportional product (FCR-CE) commits the same meter MW both ways, and
+    the marginal PUE above ``mu`` differs from the one below it (the L^2 /
+    L^3 terms grow with load), so each direction gets its own correction.
+    """
+    rho = jnp.maximum(_farr(rho), 1e-6)
+    hi = facility_power(jnp.minimum(mu + rho, 1.0), 1.0, t_amb,
+                        pue_design=pue_design)
+    lo = facility_power(mu, 1.0, t_amb, pue_design=pue_design)
+    return (hi - lo) / rho

@@ -36,7 +36,13 @@ thresholds sit inside ordinary frequency noise -- replaying them through
 this state machine detects wander crossings as activations and holds
 each for the full ``min_duration_s``.  That is the correct reading of
 the threshold semantics, but not a model of how those products are
-called; the E9 benchmark sells FFR and FCR-D only.
+called.
+
+Proportional products (FCR-CE) have no events: every second the site
+answers the signed activation :func:`droop_activation`, the engine sums
+the required and delivered meter response per settlement block, and a
+block that tracked badly forfeits its capacity payment
+(:func:`block_verdicts`, :func:`block_clawback`).
 """
 from __future__ import annotations
 
@@ -372,3 +378,43 @@ def reserve_replay_reference(freq, mu_h, t_amb_h, valid_s, product_idx, rho,
         ptr = int(np.searchsorted(cand, last + 1, side="left"))
     return dict(events=ReserveEvents(**ev), n_events=n, active_s=active_s,
                 shed_it_mwh=shed_it_mwh)
+
+
+# ---------------------------------------------------------------------------
+# Proportional products: droop activation and block settlement
+# ---------------------------------------------------------------------------
+
+
+def droop_activation(freq, deadband_hz, full_hz):
+    """Signed activation in [-1, 1] of a proportional product at 1 Hz:
+    ``sign(df) * max(|df| - deadband, 0) / (full - deadband)`` with
+    ``df = 50 - f``.  Positive under-frequency (the site draws less),
+    negative over-frequency (it draws more), 0 inside the deadband."""
+    df = markets.NOMINAL_HZ - freq
+    mag = jnp.maximum(jnp.abs(df) - deadband_hz, 0.0) / (full_hz
+                                                          - deadband_hz)
+    return jnp.clip(jnp.sign(df) * mag, -1.0, 1.0)
+
+
+def to_blocks(per_hour, block_h: int):
+    """(..., H) hourly sums -> (..., ceil(H / block_h)) block sums; the
+    last block is right-padded with zero hours."""
+    h = per_hour.shape[-1]
+    pad = (-h) % block_h
+    x = jnp.pad(per_hour, [(0, 0)] * (per_hour.ndim - 1) + [(0, pad)])
+    return jnp.sum(x.reshape(x.shape[:-1] + (-1, block_h)), axis=-1)
+
+
+def block_verdicts(abs_err, active_s, committed, tol):
+    """A block complies when its mean |delivered - required| response over
+    its active seconds is at most ``tol`` times the committed band (the
+    same units as ``abs_err / active_s``); a block with no active second
+    has nothing to answer and complies."""
+    mean_err = abs_err / jnp.maximum(active_s, 1.0)
+    return (active_s == 0) | (mean_err <= tol * committed)
+
+
+def block_clawback(ok, valid, capacity_eur) -> jax.Array:
+    """Revenue forfeited over blocks: each valid block that failed its
+    verdict loses its whole capacity payment."""
+    return jnp.sum(jnp.where(valid & ~ok, capacity_eur, 0.0), axis=-1)

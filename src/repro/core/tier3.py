@@ -77,6 +77,16 @@ def _farr(x) -> jax.Array:
     return x.astype(jnp.result_type(x.dtype, jnp.float32))
 
 
+def headroom_ok(mu, rho, *, symmetric: bool = False):
+    """Whether (mu, rho) can deliver its band: a shed must leave at least
+    MIN_RESIDUAL_LOAD, and a symmetric (proportional) band must also be
+    able to rise by rho without passing design power."""
+    ok = (mu - rho) >= MIN_RESIDUAL_LOAD
+    if symmetric:
+        ok = ok & ((mu + rho) <= 1.0)
+    return ok
+
+
 def q_ffr(mu, rho, t_amb, *, pue_aware: bool, pue_design=pue_lib.PUE_DESIGN):
     """Relative FR-provision quality in [0, 1], evaluated at the meter.
 
@@ -90,7 +100,7 @@ def q_ffr(mu, rho, t_amb, *, pue_aware: bool, pue_design=pue_lib.PUE_DESIGN):
     """
     mu = _farr(mu)
     rho = _farr(rho)
-    feasible = (mu - rho) >= MIN_RESIDUAL_LOAD
+    feasible = headroom_ok(mu, rho)
     committed_meter = rho * pue_design  # static-PUE bid
     if pue_aware:
         # choose the IT band that truly delivers `committed_meter` at the
@@ -169,9 +179,48 @@ def event_verdict(mu, t_amb, rho, product_idx, pue_design,
                 delivered_ok=delivered_ok)
 
 
+def droop_bands(mu, t_amb, rho, pue_design, pue_aware: bool = True):
+    """IT-side bands (down, up) of a symmetric proportional product.
+
+    The commitment is ``rho * PUE_design`` meter MW either way.  A
+    PUE-aware site inverts the meter gain of each direction
+    (:func:`pue.ffr_meter_gain` below ``mu``, :func:`pue.meter_gain_up`
+    above it), so full activation moves the meter by the committed amount
+    in both; a blind one moves IT by ``rho``.  The down band keeps
+    MIN_RESIDUAL_LOAD, the up band stops at design power.
+    """
+    mu = jnp.maximum(_farr(mu), 1e-3)
+    rho = _farr(rho)
+    if pue_aware:
+        g_dn = pue_lib.ffr_meter_gain(mu, rho, t_amb, pue_design=pue_design)
+        g_up = pue_lib.meter_gain_up(mu, rho, t_amb, pue_design=pue_design)
+        dn = rho * pue_design / jnp.maximum(g_dn, 1e-3)
+        up = rho * pue_design / jnp.maximum(g_up, 1e-3)
+    else:
+        dn = up = rho
+    dn = jnp.clip(dn, 0.0, jnp.maximum(mu - MIN_RESIDUAL_LOAD, 0.0))
+    up = jnp.clip(up, 0.0, jnp.maximum(1.0 - mu, 0.0))
+    return dn, up
+
+
+def droop_accuracy(mu, t_amb, rho, pue_design, pue_aware: bool = True):
+    """Meter response at full activation over the commitment, (down, up):
+    1 where the bands deliver exactly, below where they fall short."""
+    mu = jnp.maximum(_farr(mu), 1e-3)
+    rho = _farr(rho)
+    dn, up = droop_bands(mu, t_amb, rho, pue_design, pue_aware)
+    committed = jnp.maximum(rho * pue_design, 1e-6)
+    acc_dn = pue_lib.ffr_meter_gain(mu, dn, t_amb,
+                                    pue_design=pue_design) * dn / committed
+    acc_up = pue_lib.meter_gain_up(mu, up, t_amb,
+                                   pue_design=pue_design) * up / committed
+    return acc_dn, acc_up
+
+
 def revenue_score(mu, rho, t_amb, product_idx, *, pue_aware: bool,
                   pue_design=pue_lib.PUE_DESIGN,
-                  events_per_day=EVENTS_PER_DAY_DEFAULT) -> jax.Array:
+                  events_per_day=EVENTS_PER_DAY_DEFAULT,
+                  symmetric: bool = False) -> jax.Array:
     """Expected reserve-settlement net revenue of a committed band, in
     units of the product's full-band capacity rate (so ~[-1, 1] after the
     clip below).
@@ -184,8 +233,20 @@ def revenue_score(mu, rho, t_amb, product_idx, *, pue_aware: bool,
     same :func:`event_verdict` physics.  This is the Tier-3 price
     feedback: cells whose governor-limited ``t_full`` or PUE shortfall
     would forfeit revenue score negative and are avoided.
+
+    ``symmetric`` (static) prices a proportional product, which is settled
+    per block: the band earns its capacity unless a full activation either
+    way misses the commitment by more than the product's tracking
+    tolerance, which forfeits the block (``reserve.block_clawback``).
     """
     rho = _farr(rho)
+    if symmetric:
+        acc_dn, acc_up = droop_accuracy(mu, t_amb, rho, pue_design,
+                                        pue_aware)
+        tol = markets.DROOP.tracking_tol
+        forfeit = ((jnp.abs(1.0 - acc_dn) > tol)
+                   | (jnp.abs(1.0 - acc_up) > tol)).astype(rho.dtype)
+        return (rho / RHO_MAX) * (1.0 - forfeit)
     v = event_verdict(mu, t_amb, rho, product_idx, pue_design,
                       pue_aware=pue_aware)
     shortfall = jnp.clip(1.0 - v["delivered_frac"], 0.0, 1.0)
@@ -262,7 +323,8 @@ def grid_candidates(rho_fixed=0.0, *, fix_rho: bool = False):
 def point_objective(mu, rho, greenness, t_amb, weights, product_idx,
                     events_per_day, clock_w, ckpt_cost_s, *,
                     pue_aware: bool, use_revenue: bool, use_workload: bool,
-                    pue_design=pue_lib.PUE_DESIGN, price_rel=None):
+                    pue_design=pue_lib.PUE_DESIGN, price_rel=None,
+                    symmetric: bool = False):
     """The hourly selection objective J(mu, rho) at arbitrary points.
 
     Exactly the term order the grid search compiles -- q/cfe always,
@@ -271,13 +333,16 @@ def point_objective(mu, rho, greenness, t_amb, weights, product_idx,
     ``select_operating_points`` bit-for-bit.  ``price_rel`` (the bidder's
     capacity-price realisation relative to nominal) scales the revenue
     term; ``None`` omits the multiply entirely, keeping the legacy graph.
+    ``symmetric`` (static, proportional products) rules out every cell
+    without headroom both ways (:func:`headroom_ok`): its J is -inf.
     """
     q = q_ffr(mu, rho, t_amb, pue_aware=pue_aware, pue_design=pue_design)
     J = weights[0] * q + weights[1] * cfe_score(mu, greenness)
     if use_revenue:
         rev = revenue_score(
             mu, rho, t_amb, product_idx, pue_aware=pue_aware,
-            pue_design=pue_design, events_per_day=events_per_day)
+            pue_design=pue_design, events_per_day=events_per_day,
+            symmetric=symmetric)
         if price_rel is not None:
             rev = price_rel * rev
         J = J + weights[2] * rev
@@ -285,13 +350,15 @@ def point_objective(mu, rho, greenness, t_amb, weights, product_idx,
         J = J + weights[3] * throughput_score(
             mu, rho, clock_w, product_idx,
             events_per_day=events_per_day, ckpt_cost_s=ckpt_cost_s)
+    if symmetric:
+        J = jnp.where(headroom_ok(mu, rho, symmetric=True), J, -jnp.inf)
     return J
 
 
 def _select_impl(greenness, t_amb, weights, pue_design, product_idx,
                  events_per_day, rho_fixed, clock_w, ckpt_cost_s, *,
                  pue_aware: bool, use_revenue: bool, fix_rho: bool,
-                 use_workload: bool):
+                 use_workload: bool, symmetric: bool = False):
     """Vectorised (B,)-hour grid search.  Traced once per (shape, static)
     combination; all scalar knobs (weights, pue_design, product, rho,
     clock_w, ckpt cost) are traced operands so selector instances share
@@ -303,7 +370,8 @@ def _select_impl(greenness, t_amb, weights, pue_design, product_idx,
     J = point_objective(
         MU[None], RHO[None], g, ta, weights, product_idx, events_per_day,
         clock_w, ckpt_cost_s, pue_aware=pue_aware, use_revenue=use_revenue,
-        use_workload=use_workload, pue_design=pue_design)
+        use_workload=use_workload, pue_design=pue_design,
+        symmetric=symmetric)
     flat = J.reshape(J.shape[0], -1)
     idx = jnp.argmax(flat, axis=-1)
     return MU.reshape(-1)[idx], RHO.reshape(-1)[idx]
@@ -311,7 +379,8 @@ def _select_impl(greenness, t_amb, weights, pue_design, product_idx,
 
 _select_jit = jax.jit(
     _select_impl,
-    static_argnames=("pue_aware", "use_revenue", "fix_rho", "use_workload"))
+    static_argnames=("pue_aware", "use_revenue", "fix_rho", "use_workload",
+                     "symmetric"))
 
 
 def _pad_weights(weights) -> jax.Array:
@@ -340,7 +409,8 @@ def select_operating_points(greenness, t_amb, *, pue_aware: bool,
                             ckpt_cost_s=workload_lib.DEFAULT_GRID_CKPT_S,
                             use_revenue: bool = False,
                             fix_rho: bool = False,
-                            use_workload: bool = False) -> OperatingPoint:
+                            use_workload: bool = False,
+                            symmetric: bool = False) -> OperatingPoint:
     """Functional hourly grid search: (B,) greenness/t_amb -> (B,) (mu, rho).
 
     ``fix_rho=True`` restricts the search to the (traced) committed band
@@ -349,7 +419,8 @@ def select_operating_points(greenness, t_amb, *, pue_aware: bool,
     ``use_workload=True`` adds ``weights[3] * throughput_score`` with the
     (traced) mix clock weight ``clock_w`` and per-event checkpoint cost;
     False keeps the traced graph identical to the pre-workload selector.
-    Pure jnp and jit-compiled once at module level; safe to call inside
+    ``symmetric=True`` selects for a proportional product: only cells with
+    headroom both ways (:func:`headroom_ok`).  Pure jnp and jit-compiled once at module level; safe to call inside
     an outer jit.
     """
     g = jnp.asarray(greenness, jnp.float32).reshape(-1)
@@ -366,7 +437,7 @@ def select_operating_points(greenness, t_amb, *, pue_aware: bool,
         jnp.asarray(clock_w, jnp.float32),
         jnp.asarray(ckpt_cost_s, jnp.float32),
         pue_aware=pue_aware, use_revenue=use_revenue, fix_rho=fix_rho,
-        use_workload=use_workload)
+        use_workload=use_workload, symmetric=symmetric)
     return OperatingPoint(mu=mu, rho=rho)
 
 

@@ -7,7 +7,9 @@ Composes all three tiers over a simulated fleet at 1 Hz (Tier-2 cadence):
   Tier-1 (200 Hz)  represented quasi-statically at the 1 Hz tick (the PID
                    settles in <30 ms << 1 s; its transient behaviour is
                    exercised separately by E2/E4/E7 at full rate),
-  FFR events       instant envelope shed to (mu - rho) via the island path.
+  FFR events       instant envelope shed to (mu - rho) via the island path,
+  FCR-CE droop     envelope mu - rho * a(t) every second, both directions
+                   (:func:`droop_tick`).
 
 Everything is one `jax.lax.scan` over seconds with vector state across
 hosts*chips, which is how the twin reaches the paper's >26 000x real-time
@@ -293,8 +295,15 @@ def twin_tick(n_hosts: int, chips_per_host: int, chip_tdp: float,
     shed_target = jnp.clip(frac * chip_tdp, idle_floor, caps)
     target = jnp.where(ffr, jnp.minimum(target, shed_target), target)
     # 1 s >> tau and >> the ~100 ms governor ramp: quasi-static
-    chip_power = target
+    rls, out = _settle(rls, target, pred, envelope, ffr, design_host,
+                       design_it_w, pue_design, t_amb)
+    return (rls, target, caps, kk), out
 
+
+def _settle(rls, chip_power, pred, envelope, active, design_host,
+            design_it_w, pue_design, t_amb):
+    """The tick's plant settles at ``chip_power``: Tier-2's RLS update on
+    the new host power, the meter, and the metrics row."""
     host_power = jnp.sum(chip_power, axis=1)  # (H,)
     rls, abs_err_norm = ar4_lib.rls_update(rls, host_power / design_host)
     abs_err = abs_err_norm * design_host
@@ -313,10 +322,84 @@ def twin_tick(n_hosts: int, chips_per_host: int, chip_tdp: float,
         envelope=envelope,
         it_power=it,
         facility_power=fac,
-        ffr_active=ffr,
+        ffr_active=active,
         tracking_err=track,
     )
-    return (rls, chip_power, caps, kk), out
+    return rls, out
+
+
+def host_mean_demand(n_hosts: int) -> np.ndarray:
+    """(H,) long-run mean demand of each simulated host's archetype: the
+    archetype mean, and for a bursty host its duty-weighted mean of the
+    busy and idle phases."""
+    mean = np.array([plant_lib._ARCHETYPES[w]["mean"] for w in
+                     ("matmul", "inference", "bursty")],
+                    np.float32)[_host_kinds(n_hosts)]
+    bursty = _host_kinds(n_hosts) == 2
+    idle = plant_lib.BURSTY_DUTY * mean + (1.0 - plant_lib.BURSTY_DUTY) \
+        * plant_lib.BURSTY_LOW
+    return np.where(bursty, idle, mean).astype(np.float32)
+
+
+def site_scale(n_chips: int, chip_tdp: float, mw):
+    """How far the simulated chips' fluctuations shrink at the site's size:
+    ``sqrt(n_chips / site_chips)``, with ``site_chips = mw / chip_tdp``
+    the chips a site of ``mw`` design IT MW holds.  Each simulated host
+    stands for a population of independent hosts of its archetype, whose
+    deviations from the archetype mean average out as one over the root
+    of their number."""
+    site_chips = jnp.asarray(mw, jnp.float32) * 1e6 / chip_tdp
+    return jnp.minimum(jnp.sqrt(n_chips / site_chips), 1.0)
+
+
+def site_demand(load, mean, scale):
+    """(H,) simulated demand -> the demand of the populations they stand
+    for: the archetype mean plus ``scale`` times the deviation from it."""
+    return mean + scale * (load - mean)
+
+
+def droop_tick(n_hosts: int, chips_per_host: int, chip_tdp: float,
+               pue_design, carry, load_h, mu, band_dn, band_up, act, t_amb,
+               noise_scale):
+    """The 1 Hz tick under a proportional product (FCR-CE droop).
+
+    ``act`` in [-1, 1] is the second's signed activation: the envelope
+    becomes ``mu - band * act``, with the down band for ``act > 0`` and the
+    up band for ``act < 0`` (``tier3.droop_bands``: each direction's PUE
+    correction), and demand scales by ``frac / mu`` within [0, 1] -- duty
+    is shed under-frequency and the held duty released over-frequency.
+    ``load_h`` is the site's demand (:func:`site_demand`) and the chips'
+    plant noise shrinks by the same ``noise_scale`` (:func:`site_scale`).
+    """
+    H, C = n_hosts, chips_per_host
+    design_host = C * chip_tdp
+    design_it_w = H * design_host
+    rls, chip_power, caps, kk = carry
+    kk, k1 = jax.random.split(kk)
+
+    with jax.named_scope("engine.droop"):
+        band = jnp.where(act > 0, band_dn, band_up)
+        frac = mu - band * act
+        envelope = frac * design_it_w
+        host_env = jnp.full((H,), 1.0) * (frac * design_host)
+        load_r = jnp.clip(load_h * frac / jnp.maximum(mu, 1e-3), 0.0, 1.0)
+
+    pred = ar4_lib.predict(rls) * design_host  # (H,) W
+    prev = jnp.maximum(chip_power, plant_lib.P_IDLE)
+    caps = ar4_lib.host_rebalance(pred, host_env, prev, plant_lib.CAP_MIN,
+                                  plant_lib.CAP_MAX)
+    noise = 2.0 * noise_scale * jax.random.normal(k1, (H, C))
+    target = jnp.minimum(
+        plant_lib.power_model(plant_lib.F_NOMINAL, load_r[:, None]) + noise,
+        caps)
+    # under-frequency the deep shed may idle chips below the cap floor,
+    # as in an FFR activation
+    shed_target = jnp.clip(frac * chip_tdp, 53.0, caps)
+    target = jnp.where(act > 0, jnp.minimum(target, shed_target), target)
+
+    rls, out = _settle(rls, target, pred, envelope, act != 0, design_host,
+                       design_it_w, pue_design, t_amb)
+    return (rls, target, caps, kk), out
 
 
 def _twin_scan_impl(cfg: TwinConfig, inputs: TwinInputs):

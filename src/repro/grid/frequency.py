@@ -13,6 +13,12 @@ Trace semantics are pinned element-wise against
 event ramps down from 50 Hz at ``rocof`` Hz/s, bottoms at ``nadir`` and
 recovers linearly over ``recovery_s``; events are applied in ascending-time
 order with overwrite semantics on overlapping seconds.
+
+A proportional product (FCR-CE, ``markets.DROOP``) answers the whole
+deviation, so its baseline is not the fading random walk but a stationary
+mean-reverting (Ornstein-Uhlenbeck) deviation of the droop rule's
+``ou_sigma_hz`` and ``ou_tau_s`` (:func:`ou_baseline`), with the same
+Poisson excursions painted over it.
 """
 from __future__ import annotations
 
@@ -22,7 +28,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.grid.markets import FR_PRODUCTS, NOMINAL_HZ, PRODUCT_ORDER
+from repro.grid.markets import DROOP, FR_PRODUCTS, NOMINAL_HZ, PRODUCT_ORDER
 
 MAX_EVENTS = 64                 # Poisson(rate * days) tail headroom
 DEFAULT_ROCOF_HZ_S = 0.2
@@ -77,17 +83,41 @@ def sample_events(key, n_seconds: int, product_idx,
 
 def baseline_wander(key, n_seconds: int) -> jax.Array:
     """Nominal 50 Hz plus the normalised random-walk wander of
-    ``FFRTriggerGen.frequency_trace`` (std ~10 mHz).
+    ``FFRTriggerGen.frequency_trace``, the baseline of the triggered
+    products.
 
-    The wander stays far from the fast-product triggers (FFR 49.7,
-    FCR-D 49.9) but crosses the 49.98/49.99 Hz thresholds of the slow
-    restoration products on ordinary noise -- as real grid frequency
-    does.  Threshold-crossing replay is therefore only meaningful for the
-    event-activated products; see the note in ``repro.core.reserve``.
+    ``cumsum / sqrt(t)`` keeps the wander's standard deviation at about
+    10 mHz, but the walk is normalised by the elapsed time, so late in a
+    day it hardly moves from second to second.  It stays far from the
+    fast-product triggers (FFR 49.7, FCR-D 49.9); a proportional product,
+    which answers every millihertz, takes :func:`ou_baseline` instead.
     """
     g = jax.random.normal(key, (n_seconds,))
     scale = jnp.sqrt(jnp.arange(1, n_seconds + 1, dtype=jnp.float32))
     return NOMINAL_HZ + 0.01 * jnp.cumsum(g) / scale
+
+
+def ou_baseline(key, n_seconds: int, sigma_hz, tau_s) -> jax.Array:
+    """Nominal 50 Hz plus a stationary Ornstein-Uhlenbeck deviation at 1 Hz:
+    ``x_0 = sigma g_0``, ``x_t = phi x_{t-1} + sigma sqrt(1 - phi^2) g_t``
+    with ``phi = exp(-1 / tau_s)``, so every second has standard deviation
+    ``sigma_hz`` and neighbouring seconds correlate as ``phi``.
+
+    The recursion is linear, so it runs as a parallel prefix
+    (``associative_scan`` over the affine maps ``x -> phi x + b_t``), not
+    as ``n_seconds`` dependent steps.
+    """
+    phi = jnp.exp(-1.0 / jnp.asarray(tau_s, jnp.float32))
+    g = jax.random.normal(key, (n_seconds,))
+    first = jnp.arange(n_seconds) == 0
+    b = sigma_hz * jnp.where(first, 1.0, jnp.sqrt(1.0 - phi * phi)) * g
+    a = jnp.broadcast_to(phi, (n_seconds,))
+
+    def compose(earlier, later):
+        return later[0] * earlier[0], later[0] * earlier[1] + later[1]
+
+    _, x = jax.lax.associative_scan(compose, (a, b))
+    return NOMINAL_HZ + x
 
 
 def apply_events(f_base, events: EventBatch,
@@ -121,23 +151,33 @@ def apply_events(f_base, events: EventBatch,
 def frequency_trace(key, n_seconds: int, product_idx=0,
                     events_per_day=DEFAULT_EVENTS_PER_DAY,
                     rocof_hz_s: float = DEFAULT_ROCOF_HZ_S,
-                    max_events: int = MAX_EVENTS):
-    """One scenario's (trace, events).  Pure jnp; vmapped by the batch API."""
+                    max_events: int = MAX_EVENTS,
+                    proportional: bool = False):
+    """One scenario's (trace, events).  Pure jnp; vmapped by the batch API.
+    ``proportional`` (static) takes the product's OU baseline in place of
+    the wander."""
     kw, ke = jax.random.split(key)
     events = sample_events(ke, n_seconds, product_idx, events_per_day,
                            max_events)
-    return apply_events(baseline_wander(kw, n_seconds), events,
-                        rocof_hz_s), events
+    if proportional:
+        base = ou_baseline(kw, n_seconds, DROOP.ou_sigma_hz,
+                           DROOP.ou_tau_s)
+    else:
+        base = baseline_wander(kw, n_seconds)
+    return apply_events(base, events, rocof_hz_s), events
 
 
-@partial(jax.jit, static_argnames=("n_seconds", "max_events"))
+@partial(jax.jit, static_argnames=("n_seconds", "max_events",
+                                   "proportional"))
 def synthesize_frequency_batch(seeds, product_idx, *, n_seconds: int,
                                events_per_day=DEFAULT_EVENTS_PER_DAY,
-                               max_events: int = MAX_EVENTS):
+                               max_events: int = MAX_EVENTS,
+                               proportional: bool = False):
     """(N,) seeds + (N,) product indices -> ((N, T) traces, EventBatch).
 
     ONE compiled vmap: the whole scenario batch's frequency synthesis --
-    Poisson draws, ramp painting, baseline wander -- in a single call.
+    Poisson draws, ramp painting, baseline -- in a single call.  A batch
+    is all triggered or all ``proportional`` products (static).
     """
     seeds = jnp.asarray(seeds, jnp.uint32)
     product_idx = jnp.broadcast_to(jnp.asarray(product_idx, jnp.int32),
@@ -147,6 +187,7 @@ def synthesize_frequency_batch(seeds, product_idx, *, n_seconds: int,
 
     def one(seed, pidx, r):
         return frequency_trace(jax.random.PRNGKey(seed), n_seconds, pidx,
-                               r, max_events=max_events)
+                               r, max_events=max_events,
+                               proportional=proportional)
 
     return jax.vmap(one)(seeds, product_idx, rate)

@@ -1,8 +1,19 @@
 """European frequency-response product definitions + trigger generation.
 
-Activation budgets from the paper's Sect. 1-2: the Nordic FFR requires full
-reserve delivery within 700 ms of the frequency crossing 49.7 Hz; FCR has a
-30 s budget; aFRR/mFRR are the slower restoration products (PICASSO/MARI).
+Two kinds of product.  A *triggered* product is an under-frequency shed:
+the site drops its band when the frequency crosses ``trigger_hz`` and
+holds it for ``min_duration_s``.  The Nordic FFR (full delivery within
+700 ms of 49.7 Hz) and FCR-D (49.9 Hz) are triggered; so are the ``FCR``,
+``aFRR`` and ``mFRR`` entries, which are threshold stand-ins for products
+that are really dispatched continuously.
+
+A *proportional* product answers every second in proportion to the
+deviation, in both directions.  ``FCR-CE`` is the Continental Europe FCR
+of Commission Regulation (EU) 2017/1485 (SOGL) Annex V: no response
+inside a 10 mHz insensitivity band, full activation at +-200 mHz within
+30 s, the same band up and down, bought by the FCR Cooperation's daily
+auction in six 4-hour blocks.
+
 The trigger generator produces Poisson under-frequency excursions with a
 realistic ROCOF so E7 and the twin replay TSO-style activations.
 """
@@ -16,6 +27,29 @@ NOMINAL_HZ = 50.0
 
 
 @dataclass(frozen=True)
+class Droop:
+    """The response rule of a proportional product.
+
+    Every second the site answers a(t) = sign(df) max(|df| - deadband_hz,
+    0) / (full_activation_hz - deadband_hz), within [-1, 1], with df =
+    50 - f.  The band is sold and settled in blocks of ``block_h`` hours;
+    a block complies when its mean |delivered - required| meter response
+    over its active seconds is at most ``tracking_tol`` of the committed
+    meter MW, and a failed block forfeits its capacity payment.  The
+    frequency it answers is a mean-reverting (Ornstein-Uhlenbeck)
+    deviation of ``ou_sigma_hz`` and ``ou_tau_s`` under the Poisson
+    excursions.
+    """
+
+    deadband_hz: float
+    full_activation_hz: float
+    block_h: int
+    ou_sigma_hz: float
+    ou_tau_s: float
+    tracking_tol: float
+
+
+@dataclass(frozen=True)
 class FRProduct:
     name: str
     activation_budget_ms: float
@@ -26,7 +60,15 @@ class FRProduct:
     # Nordic/ENTSO-E auction order of magnitude: the fast products clear
     # high because few assets pre-qualify.
     capacity_price_eur_mw_h: float = 10.0
+    # a proportional product's response rule; None for a triggered one
+    droop: Droop | None = None
 
+
+# Continental Europe FCR (SOGL Annex V; FCR Cooperation auction); the OU
+# deviation and the tracking tolerance are assumed
+# (bench/configs/continental-fcr.json)
+DROOP = Droop(deadband_hz=0.010, full_activation_hz=0.200, block_h=4,
+              ou_sigma_hz=0.020, ou_tau_s=60.0, tracking_tol=0.10)
 
 FR_PRODUCTS: dict[str, FRProduct] = {
     # Nordic Fast Frequency Reserve: the strictest European product
@@ -35,6 +77,11 @@ FR_PRODUCTS: dict[str, FRProduct] = {
     "FCR": FRProduct("FCR", 30_000.0, 49.98, 49.8, 900.0, 15.0),
     "aFRR": FRProduct("aFRR", 300_000.0, 49.99, 49.9, 3600.0, 9.0),
     "mFRR": FRProduct("mFRR", 750_000.0, 49.99, 49.9, 3600.0, 5.0),
+    # the one proportional product: the trigger and full-delivery
+    # frequencies are the deadband edge and the full-activation point; the
+    # price is assumed
+    "FCR-CE": FRProduct("FCR-CE", 30_000.0, 49.99, 49.8, 900.0, 12.0,
+                        droop=DROOP),
 }
 
 # Stable product indexing for the batched reserve engine: a scenario's
@@ -51,6 +98,10 @@ MIN_DURATION_S = np.asarray([p.min_duration_s for p in _P], np.float32)
 CAPACITY_PRICE_EUR_MW_H = np.asarray(
     [p.capacity_price_eur_mw_h for p in _P], np.float32)
 del _P
+
+
+def is_proportional(product: str) -> bool:
+    return FR_PRODUCTS[product].droop is not None
 
 
 class FFRTriggerGen:

@@ -16,7 +16,7 @@ combos with mixed horizons -- stack into a single rectangular batch.
 from __future__ import annotations
 
 import itertools
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Sequence
 
 import jax
@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.core.pue as pue_lib
-from repro.grid.markets import PRODUCT_ORDER
+from repro.grid.markets import PRODUCT_ORDER, is_proportional
 from repro.grid.signals import COUNTRY_ORDER, synthesize_ci, synthesize_t_amb
 from repro.workload.model import MIX_ORDER, mix_index
 
@@ -96,6 +96,9 @@ class ScenarioBatch:
     reserve_rho: jax.Array   # (N,) float32 committed FR band
     event_seed: jax.Array    # (N,) int32 frequency-event draw
     mix_idx: jax.Array       # (N,) int32 index into workload.MIX_ORDER
+    # static: every product of the batch is proportional (FCR-CE), not
+    # triggered; the engine compiles the droop scan for it
+    proportional: bool = field(default=False, metadata=dict(static=True))
 
     @property
     def n(self) -> int:
@@ -147,9 +150,13 @@ def build_scenario_batch(specs: Sequence[ScenarioSpec],
     horizon in ``specs``).  Streaming sweeps pass the *global* maximum so
     every chunk stacks to one shape (one compiled program); it must cover
     the longest horizon present.
+
+    A batch holds one kind of product (:func:`product_kind`): triggered
+    and proportional scenarios replay through different scans.
     """
     if not specs:
         raise ValueError("empty scenario list")
+    proportional = product_kind(specs)
     h_need = max(s.horizon_h for s in specs)
     if h_max is None:
         h_max = h_need
@@ -188,7 +195,19 @@ def build_scenario_batch(specs: Sequence[ScenarioSpec],
         event_seed=jnp.asarray([s.event_seed for s in specs], jnp.int32),
         mix_idx=jnp.asarray(
             [mix_index(s.workload_mix) for s in specs], jnp.int32),
+        proportional=proportional,
     )
+
+
+def product_kind(specs: Sequence[ScenarioSpec]) -> bool:
+    """True if every spec sells a proportional product, False if every
+    spec sells a triggered one; a mixture is refused."""
+    kinds = {is_proportional(s.product) for s in specs}
+    if len(kinds) > 1:
+        raise ValueError(
+            "a scenario batch sells either triggered or proportional "
+            "products, not both: split the specs by product kind")
+    return kinds.pop()
 
 
 def scenario_chunk(specs: Sequence[ScenarioSpec], lo: int, hi: int, *,
