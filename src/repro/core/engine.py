@@ -136,8 +136,8 @@ class EngineConfig:
     warmup_s: int = 60          # RLS warm-up excluded from error metrics
     # scan unroll.  1 measures fastest on CPU for this op-heavy body: the
     # tick is dispatch-latency bound, and unrolling multiplies the body's
-    # op count without enabling extra fusion across the RLS/percentile
-    # barriers (unlike the tiny detection-only scan, where unroll=8 wins).
+    # op count without enabling extra fusion across the RLS barriers
+    # (unlike the tiny detection-only scan, where unroll=8 wins).
     unroll: int = 1
 
     def __post_init__(self):

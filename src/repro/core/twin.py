@@ -300,6 +300,38 @@ def twin_tick(n_hosts: int, chips_per_host: int, chip_tdp: float,
     return (rls, target, caps, kk), out
 
 
+def top2(x):
+    """The largest and second largest of all of ``x``'s values, ties kept
+    and NaN propagated, by one running max/min fold (no sort)."""
+    xs = x.reshape(-1)
+    t1, t2 = xs[0], jnp.full((), -jnp.inf, xs.dtype)
+    for i in range(1, xs.shape[0]):
+        t2 = jnp.maximum(t2, jnp.minimum(t1, xs[i]))
+        t1 = jnp.maximum(t1, xs[i])
+    return t1, t2
+
+
+def p95(x):
+    """``jnp.percentile(x, 95.0)`` over all of float32 ``x``.  The two
+    ranks it interpolates are fixed by the static size n: where both lie
+    among the two largest values (n <= 21), they come from :func:`top2`,
+    which fuses with the tick's elementwise work where a sort does not;
+    otherwise the sort.  Ranks and weights are ``jnp.percentile``'s own
+    ('linear' in float32), so the order statistics are exact and the
+    value agrees to rounding."""
+    n = x.size
+    q = np.float32(0.95) * np.float32(n - 1)
+    low, high = int(np.floor(q)), int(np.ceil(q))
+    if n - 1 - low > 1:
+        return jnp.percentile(x, 95.0)
+    high_w = np.float32(q - np.float32(low))
+    low_w = np.float32(1.0) - high_w
+    t1, t2 = top2(x)
+    low_v = t1 if low == n - 1 else t2
+    high_v = t1 if high == n - 1 else t2
+    return low_v * low_w + high_v * high_w
+
+
 def _settle(rls, chip_power, pred, envelope, active, design_host,
             design_it_w, pue_design, t_amb):
     """The tick's plant settles at ``chip_power``: Tier-2's RLS update on
@@ -318,7 +350,7 @@ def _settle(rls, chip_power, pred, envelope, active, design_host,
         host_pred=pred,
         ar4_abs_err=abs_err,
         chip_power_mean=jnp.mean(chip_power),
-        chip_power_p95=jnp.percentile(chip_power, 95.0),
+        chip_power_p95=p95(chip_power),
         envelope=envelope,
         it_power=it,
         facility_power=fac,
